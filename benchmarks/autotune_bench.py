@@ -15,21 +15,20 @@ Four legs, all merged into BENCH_sort.json:
     transferred winner) and still land within tolerance of the
     exhaustive best at that new length.
   * shard acceptance: the same exhaustive-vs-budgeted comparison for
-    ``autotune_shard`` on a forced-host D=4 mesh, in a subprocess
-    (``--xla_force_host_platform_device_count`` must be set before jax
-    import; the parent keeps its real topology).
+    ``autotune_shard`` on a D=4 mesh.  On a TPU it runs in this process
+    on the first D chips; on the CPU it runs in a subprocess with a
+    forced-host D-device mesh (``--xla_force_host_platform_device_count``
+    must be set before jax import; the parent keeps its real topology).
 """
 
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import tempfile
 
 _SELF = os.path.abspath(__file__)
-_ROOT = os.path.dirname(os.path.dirname(_SELF))
 
 # Match benchmarks/sort_throughput.py: the CPU container measures the
 # xla path; on TPU the pallas default kicks in via impl=None.
@@ -227,24 +226,17 @@ def run(n=1048576, max_trials=12, repeats=3, shard_d=4, shard_repeats=2):
 
 
 def _shard_leg(d: int, n_global: int, repeats: int):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + f" --xla_force_host_platform_device_count={d}"
-    ).strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in (_ROOT, os.path.join(_ROOT, "src"),
-                    env.get("PYTHONPATH", "")) if p)
-    proc = subprocess.run(
-        [sys.executable, _SELF, "--shard-child", str(d), str(n_global),
-         str(repeats)],
-        env=env, capture_output=True, text=True, timeout=900)
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"autotune shard child d={d} failed:\n{proc.stderr[-2000:]}")
-    line = next(l for l in proc.stdout.splitlines()
-                if l.startswith("RESULT "))
-    res = json.loads(line[len("RESULT "):])
+    import jax
+
+    from benchmarks.common import forced_host_child
+
+    if jax.default_backend() == "tpu":
+        # The chip belongs to this process: measure on its first d
+        # devices here, never in a child that would need the chip too.
+        res = _shard_measure(d, n_global, repeats)
+    else:
+        res = forced_host_child(_SELF, "--shard-child", d, n_global,
+                                repeats)
     rows = [dict(
         name=f"autotune/shard_d{d}/exhaustive_best",
         us_per_call=res["exh_best_us"],
@@ -270,8 +262,8 @@ def _shard_leg(d: int, n_global: int, repeats: int):
     return rows
 
 
-def _shard_child(d: int, n_global: int, repeats: int) -> None:
-    # Runs under --xla_force_host_platform_device_count=d.
+def _shard_measure(d: int, n_global: int, repeats: int) -> dict:
+    """Exhaustive vs budgeted ``autotune_shard`` on the first d devices."""
     from benchmarks.common import spearman
     from repro.core import autotune as autotune_mod
     from repro.launch.mesh import make_mesh
@@ -311,7 +303,7 @@ def _shard_child(d: int, n_global: int, repeats: int) -> None:
         lambda a: distributed_sort._sharded_argsort(a, mesh, exh.best_plan),
         x, repeats=repeats + 1,
     )
-    print("RESULT " + json.dumps(dict(
+    return dict(
         d=d, n_global=n_global,
         exh_best_us=t_exh * 1e6, exh_label=exh.best_label,
         exh_measured=len(measured),
@@ -320,12 +312,14 @@ def _shard_child(d: int, n_global: int, repeats: int) -> None:
             1 for c in bud.candidates if c.us_per_call is not None
         ),
         spearman=rho,
-    )), flush=True)
+    )
 
 
 if __name__ == "__main__":
     if len(sys.argv) == 5 and sys.argv[1] == "--shard-child":
-        _shard_child(int(sys.argv[2]), int(sys.argv[3]), int(sys.argv[4]))
+        # Runs under --xla_force_host_platform_device_count=d.
+        res = _shard_measure(*map(int, sys.argv[2:5]))
+        print("RESULT " + json.dumps(res), flush=True)
     else:
         for r in run(n=262144, max_trials=8, repeats=2):
             print(f"{r['name']},{r['us_per_call']:.1f},{r['derived']}")

@@ -2,10 +2,16 @@
 
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
 import time
 
 import jax
 import numpy as np
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def timeit(fn, *args, repeats: int = 3, warmup: int = 1):
@@ -33,6 +39,31 @@ def timeit_stats(fn, *args, repeats: int = 3, warmup: int = 1):
     med = float(np.median(ts))
     spread = float((max(ts) - min(ts)) / (2 * med)) if med > 0 else 0.0
     return med, spread
+
+
+def forced_host_child(script: str, flag: str, d: int, *args) -> dict:
+    """Run ``script flag d *args`` in a child whose CPU backend has ``d``
+    forced host devices; return the dict it prints on its ``RESULT``
+    line.  CPU only: the device count is fixed before jax is imported,
+    and on a TPU the child would find the chips held by this process."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = (
+        env.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={d}"
+    ).strip()
+    env["PYTHONPATH"] = os.pathsep.join(
+        p for p in (_ROOT, os.path.join(_ROOT, "src"),
+                    env.get("PYTHONPATH", "")) if p)
+    proc = subprocess.run(
+        [sys.executable, script, flag, str(d), *map(str, args)],
+        env=env, capture_output=True, text=True, timeout=900)
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"{os.path.basename(script)} child d={d} failed:\n"
+            f"{proc.stderr[-2000:]}")
+    line = next(l for l in proc.stdout.splitlines()
+                if l.startswith("RESULT "))
+    return json.loads(line[len("RESULT "):])
 
 
 def spearman(a, b) -> float:

@@ -52,7 +52,7 @@ def _keys(dtype: str, n: int, rng: np.random.Generator):
 def run(n=1048576, repeats=3):
     rng = np.random.default_rng(0)
     rows = []
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         cells = [(dt, False) for dt in DTYPES] + [
             (dt, True) for dt in DESC_DTYPES
         ]

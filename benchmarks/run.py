@@ -42,6 +42,9 @@ def main() -> None:
     if args.json is None:
         args.json = "" if (args.only and not merge) else "BENCH_sort.json"
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         autotune_bench,
         batched_segmented,

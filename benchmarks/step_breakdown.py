@@ -7,8 +7,8 @@ relocation (step 8) is cheap because it is one coalesced pass.
 
 On top of the per-step rows this emits A/B rows for the two hot spots
 this port optimizes:
-  * step 2 local sort — per-tile (block_rows=1) vs row-blocked Pallas
-    kernel, both interpret-mode (the container has no TPU);
+  * step 2 local sort — the row-blocked Pallas kernel (interpret mode
+    off the TPU, native on it);
   * steps 8/9 relocation + compaction — legacy scatter formulation vs
     the scatter-free gather formulation, on the xla path.
 """
@@ -175,43 +175,29 @@ def run(n=1048576, repeats=3, pallas_compare=True):
         us_per_call=t_cmp_ga * 1e6,
         derived=f"scatter-free; {t_cmp_sc / max(t_cmp_ga, 1e-12):.2f}x vs scatter"))
 
-    # --- A/B: per-tile vs row-blocked Pallas local sort (interpret). ----
-    t_pal_tile = t_pal_blk = None
+    # --- Row-blocked Pallas local sort (interpret mode off the TPU). ----
     if pallas_compare:
         v = jnp.arange(lp, dtype=jnp.int32).reshape(m, t)
         uk = u.reshape(m, t) if lp == n else jnp.pad(u, (0, lp - n)).reshape(m, t)
-
-        @functools.partial(jax.jit, static_argnames=("br",))
-        def pal_sort(uk, v, br):
-            return ops.sort_tiles(uk, v, impl="pallas", interpret=True,
-                                  block_rows=br)
-
-        t_pal_tile = timeit(lambda a, b: pal_sort(a, b, 1), uk, v,
-                            repeats=repeats)
-        t_pal_blk = timeit(lambda a, b: pal_sort(a, b, None), uk, v,
-                           repeats=repeats)
-        rows.append(dict(
-            name="step_breakdown/step2_local_sort_pallas_per_tile",
-            us_per_call=t_pal_tile * 1e6,
-            derived=f"block_rows=1, grid={m} (interpret)"))
+        pal_sort = jax.jit(
+            lambda uk, v: ops.sort_tiles(uk, v, impl="pallas"))
+        t_pal_blk = timeit(pal_sort, uk, v, repeats=repeats)
+        mode = "interpret" if ops.default_interpret() else "native"
         rows.append(dict(
             name="step_breakdown/step2_local_sort_pallas_blocked",
             us_per_call=t_pal_blk * 1e6,
-            derived=f"auto block_rows, "
-                    f"{t_pal_tile / max(t_pal_blk, 1e-12):.2f}x vs per-tile"))
+            derived=f"auto block_rows, {m} tiles ({mode})"))
 
-    # --- Acceptance row: local sort + relocation, baseline vs fused. ----
-    base_ls = t_pal_tile if t_pal_tile is not None else t_local
-    new_ls = t_pal_blk if t_pal_blk is not None else t_local
-    base = base_ls + t_rel_sc + t_cmp_sc
-    new = new_ls + t_rel_ga + t_cmp_ga
+    # --- Acceptance row: relocation + compaction, scatter vs gather. ----
+    base = t_local + t_rel_sc + t_cmp_sc
+    new = t_local + t_rel_ga + t_cmp_ga
     rows.append(dict(
         name="step_breakdown/local_sort_plus_relocation_baseline",
         us_per_call=base * 1e6,
-        derived="per-tile sort + scatter relocation/compaction"))
+        derived="xla sort + scatter relocation/compaction"))
     rows.append(dict(
         name="step_breakdown/local_sort_plus_relocation_fused",
         us_per_call=new * 1e6,
-        derived=f"blocked sort + gather relocation/compaction; "
+        derived=f"xla sort + gather relocation/compaction; "
                 f"{base / max(new, 1e-12):.2f}x speedup (n={n})"))
     return rows

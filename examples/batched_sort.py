@@ -21,6 +21,9 @@ from repro.core import (
     sort_batched,
     topk_batched,
 )
+from repro import compile_cache
+
+compile_cache.enable()
 
 rng = np.random.default_rng(0)
 

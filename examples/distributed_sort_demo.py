@@ -14,8 +14,10 @@ import numpy as np
 
 from repro.core import SortConfig, make_sharded_sort
 from repro.core.distributed_sort import trace_count
+from repro import compile_cache
 from repro.launch.mesh import make_mesh
 
+compile_cache.enable()
 mesh = make_mesh((4, 2), ("data", "model"))
 cfg = SortConfig(tile=1024, s=32, direct_max=2048, impl="xla")
 n = 1 << 17

@@ -7,10 +7,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (
     DEFAULT_CONFIG, PAPER_CONFIG, SortConfig, argsort, sort, sort_kv,
     sort_with_stats, topk,
 )
+
+compile_cache.enable()
 
 rng = np.random.default_rng(0)
 

@@ -52,6 +52,7 @@ import jax
 import numpy as np
 
 from repro.core import cost_model, faults, guard
+from repro.core import plan as plan_mod
 from repro.core.plan import (
     ShardPlan,
     SortPlan,
@@ -63,6 +64,7 @@ from repro.core.plan import (
     shard_plan_to_dict,
 )
 from repro.core.sort_config import SortConfig, next_pow2
+from repro.kernels import ops
 
 _CACHE_ENV = "REPRO_SORT_PLAN_CACHE"
 _STORE_SCHEMA = "sort_plan_cache/v1"
@@ -292,7 +294,7 @@ def candidate_space(
         out.append(Candidate(cfg=cand, label=bits or "base"))
 
     _add()  # the base config: candidate 0, the speedup reference
-    for st in ("bitonic", "radix", "merge"):
+    for st in ops.strategies_for(*plan_mod.resolve_impl(cfg)):
         if st != cfg.strategy:
             _add(strategy=st)
     for t in tiles:
@@ -851,7 +853,7 @@ def shard_candidate_space(
         ))
 
     _add("base")
-    for st in ("bitonic", "radix", "merge"):
+    for st in ops.strategies_for(*plan_mod.resolve_impl(cfg)):
         if st != cfg.strategy:
             _add(f"strategy={st}", strategy=st)
     for o in (oversample * 2, max(oversample // 2, 1), oversample * 4):

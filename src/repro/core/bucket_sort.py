@@ -6,7 +6,7 @@ Single-device deterministic sample sort.  The paper's nine steps map to
   step 1  split into tiles            -> reshape (rows, L) -> (rows*m, T)
   step 2  local sort per SM           -> row-blocked Pallas bitonic sort
                                          (block_rows tiles per grid program)
-  step 3  s equidistant local samples -> fused epilogue output of step 2
+  step 3  s equidistant local samples -> strided slice of step 2's output
   step 4  sort all samples            -> recursive call on the sample array
   step 5  s equidistant global samples-> strided slice of sorted samples
   step 6  sample indexing             -> fused Pallas splitter-partition
@@ -55,11 +55,11 @@ hi/lo pairs for int64/uint64/float64, and an order-reversing complement
 for ``SortConfig.descending``.  Every public entry point below supports
 every codec dtype; 64-bit dtypes need x64 mode enabled.
 
-Relocation/compaction are SCATTER-FREE on the default path (DESIGN.md
-§4): both passes compute, for every destination slot, the source index
-it must read (via a binary search over the chunk-offset tables) and
-gather with `take`.  XLA serializes large 1-D scatters; gathers it
-vectorizes.  ``cfg.relocation="scatter"`` keeps the legacy
+Relocation/compaction move the data by gather on the default path
+(DESIGN.md §4): both passes compute, for every destination slot, the
+source index it must read (per-chunk bases expanded over the slots by
+``_chunk_values``) and gather with `take`.  XLA serializes large 1-D
+scatters; gathers it vectorizes.  ``cfg.relocation="scatter"`` keeps the legacy
 destination-scatter formulation as a reference path.
 
 Correctness invariants (tested, incl. hypothesis properties):
@@ -174,22 +174,34 @@ def _direct_sort(kw, vals, node: LevelPlan, impl, interpret, pad_base):
     return tuple(w[:, :length] for w in sk), sv[:, :length], pad_base
 
 
-def _chunk_search(offsets, positions):
-    """For each row: index of the chunk containing each position.
+def _chunk_values(offsets, values, length: int):
+    """Expand per-chunk values to per-position values, row by row.
 
     Args:
         offsets: (Q, C) non-decreasing exclusive chunk starts
-            (offsets[:, 0] == 0).
-        positions: (Q, P) query positions.
+            (offsets[:, 0] == 0); starts at or past ``length`` are
+            ignored.
+        values: (Q, C) int32 value of each chunk.
+        length: positions per row.
     Returns:
-        (Q, P) int32 j with offsets[q, j] <= positions[q, p] <
-        offsets[q, j+1] — i.e. the LAST chunk starting at or before the
-        position, which skips empty chunks (ties in ``offsets``)
-        correctly.  Pure binary search: lowers to gathers, never a
-        scatter.
+        (Q, length) int32 with out[q, p] = values[q, j] for the LAST
+        chunk j starting at or before p, which skips empty chunks (ties
+        in ``offsets``).  The differences between consecutive chunk
+        values are added at the chunk starts and summed along the row:
+        Q*C small updates plus one cumsum over the output.  A binary
+        search per position instead gathers from the (Q, C) table at
+        every probe, which costs the TPU far more than the data it
+        selects.
     """
-    find = jax.vmap(lambda o, p: jnp.searchsorted(o, p, side="right"))
-    return find(offsets, positions).astype(jnp.int32) - 1
+    q, c = offsets.shape
+    step = jnp.concatenate(
+        [values[:, :1], values[:, 1:] - values[:, :-1]], axis=1
+    )
+    row = jax.lax.broadcasted_iota(jnp.int32, (q, c), 0)
+    marks = jnp.zeros((q, length), jnp.int32).at[row, offsets].add(
+        step, mode="drop"
+    )
+    return jnp.cumsum(marks, axis=1, dtype=jnp.int32)
 
 
 def _relocate_gather(tkw, tv, starts, tile_off, totals, r, m, s_round, t, cap,
@@ -202,22 +214,23 @@ def _relocate_gather(tkw, tv, starts, tile_off, totals, r, m, s_round, t, cap,
     of tile i = 0..m-1 of data row r' that fall in key range j; tile i's
     chunk lands at offset tile_off[r', i, j] and is read from the sorted
     tile starting at starts[r'*m + i, j].  Slot p of bucket row q
-    therefore reads from the tile whose chunk covers p (binary search
-    over the m chunk offsets), at chunk-relative position p - chunk
-    offset.  Slots past the true fill (p >= totals) become fresh pads,
-    unique within their bucket row.
+    therefore reads from the tile whose chunk covers p, at
+    chunk-relative position p - chunk offset (:func:`_chunk_values`
+    expands the per-chunk source bases over the slots).  Slots past the
+    true fill (p >= totals) become fresh pads, unique within their
+    bucket row.
     """
     # Per-bucket-row views: (r*s_round, m) chunk offsets / tile starts.
     offs = tile_off.transpose(0, 2, 1).reshape(r * s_round, m)
     st = starts.reshape(r, m, s_round).transpose(0, 2, 1).reshape(r * s_round, m)
-    p = jax.lax.broadcasted_iota(jnp.int32, (r * s_round, cap), 1)
-    src_tile = _chunk_search(offs, p)  # (r*s_round, cap) tile index
-    src_start = jnp.take_along_axis(st, src_tile, axis=1)
-    src_off = jnp.take_along_axis(offs, src_tile, axis=1)
+    # Slot p of chunk i reads flat element (row*m + i)*t + st - offs + p.
+    tile = jax.lax.broadcasted_iota(jnp.int32, (r * s_round, m), 1)
     row_base = (
-        jax.lax.broadcasted_iota(jnp.int32, (r * s_round, cap), 0) // s_round
+        jax.lax.broadcasted_iota(jnp.int32, (r * s_round, m), 0) // s_round
     ) * m
-    src = (row_base + src_tile) * t + src_start + (p - src_off)
+    base = (row_base + tile) * t + st - offs
+    p = jax.lax.broadcasted_iota(jnp.int32, (r * s_round, cap), 1)
+    src = p + _chunk_values(offs, base, cap)
     valid = p < totals.reshape(r * s_round, 1)
     src = jnp.where(valid, src, 0)
     srcf = src.reshape(-1)
@@ -272,15 +285,17 @@ def _relocate_scatter(tkw, tv, ranks, starts, tile_off, r, m, s_round, t, cap,
 
 def _compact_gather(ckw, cv, totals, r, s_round, cap, lp):
     """Step 9 compaction, scatter-free: dense column c of data row r'
-    reads from bucket j covering c (binary search over the s_round
-    bucket offsets) at position c - bucket_off.  Bucket fills sum to lp
-    per row, so every dense slot has exactly one source — no pads."""
+    reads from bucket j covering c (:func:`_chunk_values` over the
+    s_round bucket offsets) at position c - bucket_off.  Bucket fills
+    sum to lp per row, so every dense slot has exactly one source — no
+    pads."""
     bucket_off = jnp.cumsum(totals, axis=1, dtype=jnp.int32) - totals  # (r, s_round)
+    # Column c of bucket j reads flat slot (row*s_round + j)*cap - off + c.
+    bucket = jax.lax.broadcasted_iota(jnp.int32, (r, s_round), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (r, s_round), 0)
+    base = (row * s_round + bucket) * cap - bucket_off
     c = jax.lax.broadcasted_iota(jnp.int32, (r, lp), 1)
-    srcj = _chunk_search(bucket_off, c)  # (r, lp) bucket index
-    within = c - jnp.take_along_axis(bucket_off, srcj, axis=1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (r, lp), 0)
-    src = (row * s_round + srcj) * cap + within
+    src = c + _chunk_values(bucket_off, base, lp)
     srcf = src.reshape(-1)
     okw = tuple(jnp.take(w.reshape(-1), srcf).reshape(r, lp) for w in ckw)
     ov = jnp.take(cv.reshape(-1), srcf).reshape(r, lp)
@@ -474,11 +489,8 @@ def _sort_canonical_packed(keys_words, vals, plan: SortPlan, pad_base0: int,
     _TRACE_COUNT += 1  # python side effect: runs once per TRACE
     stats: list | None = [] if with_stats else None
     kw = tuple(keys_words)
-    skw, sv, pad_base = _run_node(
+    skw, sv, _ = _run_node(
         kw, vals, plan.root, plan.impl, plan.interpret, pad_base0, stats
-    )
-    assert pad_base < _INT_MAX, (
-        f"pad payload budget exhausted ({pad_base}); reduce L or raise s/tile"
     )
     if with_stats:
         return skw, sv, stats
@@ -525,14 +537,11 @@ def _fallback_plan(plan: SortPlan) -> SortPlan | None:
     for the same (rows, length) canonical-words signature.  ``None``
     when it would equal the failing plan (nothing left to vary before
     the reference path)."""
-    try:
-        alt = plan_mod.build_words_plan(
-            plan.length, plan.num_words,
-            SortConfig(impl="xla", interpret=False),
-            rows=plan.rows_padded,
-        )
-    except Exception:
-        return None
+    alt = plan_mod.build_words_plan(
+        plan.length, plan.num_words,
+        SortConfig(impl="xla", interpret=False),
+        rows=plan.rows_padded,
+    )
     return None if alt == plan else alt
 
 
@@ -546,9 +555,9 @@ def _execute_packed(kw, vals, plan: SortPlan, pad_base0: int, *,
     paper's capacity bound on the measured bucket fills of each round,
     ``'full'`` adds permutation checksums + sortedness on the output.
 
-    With ``degrade=True`` any failure — kernel launch error, injected
-    fault (``core/faults.py``), or a check violation — walks the
-    degradation chain (DESIGN.md §11):
+    With ``degrade=True`` a recoverable failure (``guard.RECOVERABLE``:
+    an injected fault from ``core/faults.py`` or a check violation)
+    walks the degradation chain (DESIGN.md §11):
 
       1. the resolved plan as given;
       2. a default-config ``impl='xla'`` stand-in plan (fresh trace —
@@ -557,12 +566,15 @@ def _execute_packed(kw, vals, plan: SortPlan, pad_base0: int, *,
       3. the ``jax.lax.sort`` reference (no plan machinery at all).
 
     Each step re-runs the checks; events land in
-    ``guard.degradation_log()``.  ``degrade=False`` (the explicit-plan
-    API) propagates the structured error instead.  Returns
+    ``guard.degradation_log()``.  Any other error — a kernel Mosaic
+    refuses, a lowering error — propagates: the chain never hides a run
+    that left its device path.  ``degrade=False`` (the explicit-plan
+    API) propagates the structured error too.  Returns
     (kw, vals[, stats]); a run degraded to the reference path reports
     ``stats == []`` (the reference has no bucket rounds).
     """
     guard.validate_check(check)
+    check_pad_budget(plan, pad_base0)
     want_stats = with_stats or check != "off"
 
     def run(p: SortPlan):
@@ -576,7 +588,7 @@ def _execute_packed(kw, vals, plan: SortPlan, pad_base0: int, *,
 
     try:
         skw, sv, stats = run(plan)
-    except Exception as e1:
+    except guard.RECOVERABLE as e1:
         if not degrade:
             raise
         alt = _fallback_plan(plan)
@@ -587,7 +599,7 @@ def _execute_packed(kw, vals, plan: SortPlan, pad_base0: int, *,
                 "default xla stand-in plan", e1)
             try:
                 skw, sv, stats = run(alt)
-            except Exception as e2:
+            except guard.RECOVERABLE as e2:
                 e1 = e2
         if skw is None:
             guard.record_degradation(
@@ -600,6 +612,30 @@ def _execute_packed(kw, vals, plan: SortPlan, pad_base0: int, *,
     if with_stats:
         return skw, sv, stats
     return skw, sv
+
+
+def check_pad_budget(plan: SortPlan, pad_base0: int) -> None:
+    """Raise before tracing when ``plan`` does not fit the int32 budget.
+
+    Payloads and flat indices are int32.  Real elements carry indices
+    below ``pad_base0``; every pad the recursion introduces draws the
+    next payload above it (``LevelPlan.pad_span``); and relocation and
+    compaction index the flattened bucket arrays, which grow about 2x
+    per bucket round (``LevelPlan.max_elements``).  Both must stay
+    below 2**31 - 1.
+
+    Raises:
+        ValueError: naming the int32 payload budget and the plan.
+    """
+    top = pad_base0 + plan.root.pad_span()
+    biggest = plan.root.max_elements()
+    if max(top, biggest) >= _INT_MAX:
+        raise ValueError(
+            f"sort of rows={plan.rows} x length={plan.length} exceeds the "
+            f"int32 payload budget: pad payloads reach {top} and the "
+            f"largest bucket array holds {biggest} elements, both must "
+            f"stay below 2**31 - 1; split the input or sort it on a mesh"
+        )
 
 
 def _sort_canonical_rows(kw, plan: SortPlan, with_stats: bool = False,

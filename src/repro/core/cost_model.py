@@ -45,7 +45,7 @@ from repro.core.sort_config import next_pow2
 # Bump on ANY change to the constants or formulas below: persisted
 # autotune records carry this tag and a mismatch at load is a clean
 # re-tune (core/autotune.plan_for / shard_plan_for).
-COST_MODEL_VERSION = "cost_model/v1"
+COST_MODEL_VERSION = "cost_model/v2"
 
 # --- model constants (DESIGN.md §10 derives each; calibrated once
 # against a measured 12-candidate slice at n=2^18, see the Spearman
@@ -62,8 +62,13 @@ SCATTER_WRITE_FACTOR = 5.0
 # binary searches (kernels/radix.py); the log term is the slot search.
 RADIX_PASS_BASE = 3.0
 # Splitter ranking compares every element against all s_round-1
-# splitters (the _lt_matrix formulation): per-element units per bucket.
+# splitters, one at a time in the partition kernel: per-element units
+# per bucket.
 RANK_UNITS_PER_BUCKET = 2.0
+# Gather relocation finds each slot's source by expanding per-chunk
+# bases over the slots (bucket_sort._chunk_values): one cumsum add and
+# one offset add per slot, whatever the chunk count.
+CHUNK_EXPAND_UNITS = 2.0
 # Merge-path per-level per-element work: the diagonal binary search is
 # amortized across each output block (fraction of log2(T) per element)
 # plus the linear merge move.
@@ -226,9 +231,9 @@ def _estimate_node(
         hbm += e * bpe
     # Steps 6-7 splitter ranking/partition: fused = one read of the
     # tiles; unfused = a ranks pass plus a partition pass.  Ranking
-    # compares every element against all s_round-1 splitters (the
-    # _lt_matrix formulation) — LINEAR in the bucket count, which is
-    # what prices the s knob.
+    # compares every element against all s_round-1 splitters, one at a
+    # time in the partition kernel — LINEAR in the bucket count, which
+    # is what prices the s knob.
     hbm += (1.0 if node.fuse_ranking else 2.0) * e * bpe
     ops += e * node.s_round * RANK_UNITS_PER_BUCKET * (nw + 1)
     # Step 8 relocation into the dense bucket array, then compaction.
@@ -236,7 +241,7 @@ def _estimate_node(
         hbm += (e * SCATTER_WRITE_FACTOR + eb) * bpe
     else:
         hbm += (e + eb) * bpe
-        ops += eb * (_log2(node.m * node.s_round) + 1)  # source search
+        ops += eb * CHUNK_EXPAND_UNITS  # source of every slot
     hbm += (eb + e) * bpe  # compaction gather back to dense rows
     vmem = _node_vmem(node, bpe)
     align = _align_factor(node)

@@ -35,9 +35,12 @@ import warnings
 
 import numpy as np
 
+from repro.core.faults import FaultInjected
+
 __all__ = [
     "CHECK_MODES",
     "SortRuntimeError",
+    "RECOVERABLE",
     "DegradationWarning",
     "DegradationEvent",
     "record_degradation",
@@ -76,6 +79,13 @@ class SortRuntimeError(RuntimeError):
         if detail:
             msg += f" — {detail}"
         super().__init__(msg)
+
+
+#: The failures a degradation chain recovers from: an injected fault and
+#: a violated runtime invariant.  Anything else (a Mosaic refusal, a
+#: lowering error, an out-of-budget size) propagates, so a run never
+#: leaves the kernels it was planned on in silence.
+RECOVERABLE = (FaultInjected, SortRuntimeError)
 
 
 class DegradationWarning(UserWarning):

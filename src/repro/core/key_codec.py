@@ -42,7 +42,7 @@ original index and descending sorts stay stable — matching
 stable=True)``.
 
 64-bit dtypes require x64 mode (``jax.config.update("jax_enable_x64",
-True)`` or the ``jax.experimental.enable_x64()`` context manager); the
+True)`` or the ``jax.enable_x64(True)`` context manager); the
 codec raises a clear error otherwise.  The 64 <-> 2x32 split uses
 ``lax.bitcast_convert_type``'s trailing-dimension form, so no 64-bit
 arithmetic is emitted — only the input/output arrays themselves are
@@ -84,7 +84,7 @@ def _require_x64(name: str) -> None:
         raise TypeError(
             f"{name} keys require x64 mode: enable it globally with "
             'jax.config.update("jax_enable_x64", True) or locally with '
-            "the jax.experimental.enable_x64() context manager"
+            "the jax.enable_x64(True) context manager"
         )
 
 

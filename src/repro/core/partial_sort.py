@@ -20,8 +20,8 @@ top-k is descending by definition.
 ``topk_batched`` runs the same partial round on every row of a
 serving-shaped (B, vocab) batch in ONE launch (DESIGN.md §5): tiles of
 all rows sort together, splitters/thresholds are per row, and the
-candidate pack is a scatter-free gather (binary search over the per-row
-tile candidate-count prefix sums, like the step-8 relocation).
+candidate pack is a gather whose source indices are expanded from the
+per-row tile candidate-count prefix sums, like the step-8 relocation.
 
 Scheduling follows the planner/executor split (DESIGN.md §7): the
 one-round geometry (lp, m, cap, ccap, kernel block sizes, resolved
@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import guard
-from repro.core.bucket_sort import _chunk_search
+from repro.core.bucket_sort import _chunk_values
 from repro.core.key_codec import codec_for
 from repro.core.plan import TopkPlan, build_topk_plan
 from repro.core.sort_config import DEFAULT_CONFIG, SortConfig, next_pow2
@@ -155,12 +155,9 @@ def _smallest_k(kw, tplan: TopkPlan):
 def _fallback_topk_plan(n, k, dtype, tplan: TopkPlan, rows: int = 1):
     """Default-config xla stand-in plan for the degradation chain
     (DESIGN.md §11), or None when indistinguishable from ``tplan``."""
-    try:
-        alt = build_topk_plan(
-            n, k, dtype, SortConfig(impl="xla", interpret=False), rows=rows
-        )
-    except Exception:
-        return None
+    alt = build_topk_plan(
+        n, k, dtype, SortConfig(impl="xla", interpret=False), rows=rows
+    )
     return None if alt == tplan else alt
 
 
@@ -219,7 +216,7 @@ def topk(x: jax.Array, k: int, cfg: SortConfig = DEFAULT_CONFIG):
     tplan = build_topk_plan(n, k, x.dtype, cfg)
     try:
         return run(tplan)
-    except Exception as e1:
+    except guard.RECOVERABLE as e1:
         alt = _fallback_topk_plan(n, k, x.dtype, tplan)
         if alt is not None:
             guard.record_degradation(
@@ -228,7 +225,7 @@ def topk(x: jax.Array, k: int, cfg: SortConfig = DEFAULT_CONFIG):
                 e1)
             try:
                 return run(alt)
-            except Exception as e2:
+            except guard.RECOVERABLE as e2:
                 e1 = e2
         guard.record_degradation(
             _topk_site(tplan), "fallback",
@@ -316,11 +313,11 @@ def _smallest_k_rows(kw, tplan: TopkPlan):
     # positions (the candidates are a sorted tile's prefix).
     tile_excl = jnp.cumsum(tile_rank, axis=1, dtype=jnp.int32) - tile_rank
     total = tile_rank.sum(axis=1, dtype=jnp.int32)  # (b,)
+    tile = jax.lax.broadcasted_iota(jnp.int32, (b, m), 1)
+    row_base = jax.lax.broadcasted_iota(jnp.int32, (b, m), 0) * m
+    base = (row_base + tile) * t - tile_excl
     p = jax.lax.broadcasted_iota(jnp.int32, (b, ccap), 1)
-    src_tile = _chunk_search(tile_excl, p)  # (b, ccap)
-    src_off = jnp.take_along_axis(tile_excl, src_tile, axis=1)
-    row_base = jax.lax.broadcasted_iota(jnp.int32, (b, ccap), 0) * m
-    src = (row_base + src_tile) * t + (p - src_off)
+    src = p + _chunk_values(tile_excl, base, ccap)
     valid = p < total[:, None]
     src = jnp.where(valid, src, 0).reshape(-1)
     ckw = tuple(
@@ -375,7 +372,7 @@ def topk_batched(x: jax.Array, k: int, cfg: SortConfig = DEFAULT_CONFIG):
     tplan = build_topk_plan(n, k, x.dtype, cfg, rows=b)
     try:
         return run(tplan)
-    except Exception as e1:
+    except guard.RECOVERABLE as e1:
         alt = _fallback_topk_plan(n, k, x.dtype, tplan, rows=b)
         if alt is not None:
             guard.record_degradation(
@@ -384,7 +381,7 @@ def topk_batched(x: jax.Array, k: int, cfg: SortConfig = DEFAULT_CONFIG):
                 e1)
             try:
                 return run(alt)
-            except Exception as e2:
+            except guard.RECOVERABLE as e2:
                 e1 = e2
         guard.record_degradation(
             _topk_site(tplan), "fallback",

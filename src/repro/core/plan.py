@@ -131,6 +131,27 @@ class LevelPlan:
             return 0
         return self.rows * self.s_round * self.cap
 
+    def max_elements(self) -> int:
+        """Largest array this node or its children hold (rows * lp, or
+        the dense bucket array) — the bound on every flat int32 index
+        the executor computes."""
+        if self.kind != "bucket":
+            return self.elements
+        return max(self.elements, self.bucket_elements,
+                   self.sample_plan.max_elements(),
+                   self.bucket_plan.max_elements())
+
+    def pad_span(self) -> int:
+        """Pad payloads this node draws above the ``pad_base`` it is
+        entered with: its own column padding, the relocation pads of a
+        bucket round (``cap`` per row), and its children's spans — the
+        same walk as the executor's ``pad_base`` threading."""
+        span = self.lp - self.length
+        if self.kind == "bucket":
+            span += (self.sample_plan.pad_span() + self.cap
+                     + self.bucket_plan.pad_span())
+        return span
+
 
 @dataclasses.dataclass(frozen=True)
 class SortPlan:
@@ -241,14 +262,28 @@ def config_fingerprint(cfg: SortConfig) -> str:
     return hashlib.sha256(blob.encode()).hexdigest()[:16]
 
 
-def _resolve_backend(cfg: SortConfig) -> tuple[str, bool, str]:
-    """(impl, interpret, backend) with the cfg Nones resolved."""
+def resolve_impl(cfg: SortConfig) -> tuple[str, bool]:
+    """(impl, interpret) with the cfg Nones resolved."""
     from repro.kernels import ops  # local import: ops imports core.key_codec
 
     impl = cfg.impl or ops.default_impl()
     interpret = (
         ops.default_interpret() if cfg.interpret is None else cfg.interpret
     )
+    return impl, interpret
+
+
+def _resolve_backend(cfg: SortConfig) -> tuple[str, bool, str]:
+    """(impl, interpret, backend) with the cfg Nones resolved.
+
+    Raises:
+        ValueError: naming ``cfg.strategy`` when it has no native TPU
+            kernel and the plan would run Pallas without interpret mode.
+    """
+    from repro.kernels import ops
+
+    impl, interpret = resolve_impl(cfg)
+    ops.check_strategy(cfg.strategy, impl, interpret)
     return impl, interpret, jax.default_backend()
 
 

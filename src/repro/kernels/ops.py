@@ -32,13 +32,32 @@ from repro.kernels import topk as _topk
 from repro.kernels.bitonic import as_words
 
 _STRATEGIES = ("bitonic", "radix", "merge")
+# Strategies whose row sort Mosaic lowers.  The radix and merge row sorts
+# gather data-dependently inside the kernel (``take_along_axis``), which
+# Mosaic refuses; they run in interpret mode and on the xla path only.
+_NATIVE_STRATEGIES = ("bitonic",)
 
 
-def _check_strategy(strategy: str) -> None:
+def strategies_for(impl: str, interpret: bool) -> tuple[str, ...]:
+    """Local-sort strategies that can run under (impl, interpret)."""
+    if impl == "pallas" and not interpret:
+        return _NATIVE_STRATEGIES
+    return _STRATEGIES
+
+
+def check_strategy(strategy: str, impl: str, interpret: bool) -> None:
+    """Raise ValueError naming ``strategy`` unless it can run under the
+    resolved (impl, interpret) pair."""
     if strategy not in _STRATEGIES:
         raise ValueError(
             f"unknown local-sort strategy {strategy!r}; "
             f"expected one of {_STRATEGIES}"
+        )
+    if strategy not in strategies_for(impl, interpret):
+        raise ValueError(
+            f"local-sort strategy {strategy!r} has no native TPU kernel "
+            "(its row sort gathers inside the kernel, which Mosaic cannot "
+            "lower); use strategy='bitonic', impl='xla', or interpret=True"
         )
 
 
@@ -141,10 +160,10 @@ def sort_tiles(
         lexicographically ascending on (*words, payload).
     """
     impl = impl or default_impl()
-    _check_strategy(strategy)
+    interpret = default_interpret() if interpret is None else interpret
+    check_strategy(strategy, impl, interpret)
     faults.check("kernel.launch")  # trace-time chaos site (DESIGN.md §11)
     if impl == "pallas":
-        interpret = default_interpret() if interpret is None else interpret
         if strategy == "radix":
             return _radix.sort_tiles_kv(
                 keys, vals, radix_bits=radix_bits, block_rows=block_rows,
@@ -188,10 +207,10 @@ def sort_tiles_sample(
         keys in the input structure.
     """
     impl = impl or default_impl()
-    _check_strategy(strategy)
+    interpret = default_interpret() if interpret is None else interpret
+    check_strategy(strategy, impl, interpret)
     faults.check("kernel.launch")  # trace-time chaos site (DESIGN.md §11)
     if impl == "pallas":
-        interpret = default_interpret() if interpret is None else interpret
         if strategy == "radix":
             return _radix.sort_tiles_sample_kv(
                 keys, vals, num_samples=num_samples, radix_bits=radix_bits,

@@ -18,10 +18,9 @@ def make_production_mesh(*, multi_pod: bool = False):
         f"need {n} devices, have {len(jax.devices())} — the dry-run sets "
         "XLA_FLAGS=--xla_force_host_platform_device_count=512 first"
     )
-    from repro.compat import mesh_axis_type_kwargs
-
     return jax.make_mesh(
-        shape, axes, devices=devices, **mesh_axis_type_kwargs(len(axes))
+        shape, axes, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )
 
 
@@ -32,9 +31,7 @@ def make_mesh(shape, axes):
     n = int(np.prod(shape))
     devices = jax.devices()[:n]
     assert len(devices) == n, (n, len(jax.devices()))
-    from repro.compat import mesh_axis_type_kwargs
-
     return jax.make_mesh(
         tuple(shape), tuple(axes), devices=devices,
-        **mesh_axis_type_kwargs(len(axes))
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
     )

@@ -152,3 +152,22 @@ def test_batched_stats_bucket_bound_adversarial_rows(rng):
     np.testing.assert_array_equal(
         np.asarray(perm), np.argsort(rows, axis=1, kind="stable")
     )
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_int32_budget_overflow_raises_before_tracing(impl):
+    """At n = 2**28 the bucket arrays outgrow int32 flat indices: the
+    sort names the payload budget instead of tracing (or degrading)."""
+    import jax
+
+    cfg = SortConfig(impl=impl, interpret=impl == "pallas")
+    x = jax.ShapeDtypeStruct((1 << 28,), jnp.int32)
+    with pytest.raises(ValueError, match="int32 payload budget"):
+        jax.eval_shape(lambda a: bucket_sort.argsort(a, cfg), x)
+
+
+def test_int32_budget_admits_largest_single_chip_size():
+    """2**27 (the chip-smoke size) stays inside the budget."""
+    n = 1 << 27
+    plan = bucket_sort.resolve_plan(n, jnp.int32, SortConfig(impl="xla"))
+    bucket_sort.check_pad_budget(plan, n)

@@ -25,97 +25,23 @@ Float caveats, pinned down so the oracle comparison is EXACT:
     include -0.0; exact PERMUTATION comparisons (argsort) drop it.
 """
 
-import contextlib
-import dataclasses
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import bucket_sort, partial_sort
-from repro.core.sort_config import SortConfig
-
-_XLA = SortConfig(tile=256, s=16, direct_max=512, impl="xla")
-_PAL = SortConfig(tile=128, s=8, direct_max=256, impl="pallas", interpret=True)
-
-CELLS = [
-    pytest.param(_XLA, id="xla-gather"),
-    pytest.param(dataclasses.replace(_XLA, relocation="scatter"),
-                 id="xla-scatter"),
-    pytest.param(_PAL, id="pallas-gather"),
-    pytest.param(dataclasses.replace(_PAL, relocation="scatter"),
-                 id="pallas-scatter"),
-]
-
-# Crosses both cells' tile (128/256) and direct_max (256/512) boundaries.
-SIZES = [1, 5, 127, 128, 255, 256, 511, 512, 513, 1500]
-
-DTYPES = ["int32", "uint32", "float32"]
-# Key-codec satellites: two-word 64-bit keys, widened bf16/bool.  Run
-# through the SAME assertions as the core 32-bit dtypes.
-WIDE_DTYPES = ["int64", "uint64", "float64", "bfloat16", "bool"]
-ALL_DTYPES = DTYPES + WIDE_DTYPES
-
-ORDERS = ["asc", "desc"]
-
-
-def dtype_ctx(dtype):
-    """enable_x64 context for the 64-bit dtypes, no-op otherwise."""
-    if dtype in ("int64", "uint64", "float64"):
-        return jax.experimental.enable_x64()
-    return contextlib.nullcontext()
-
-
-def order_cfg(cfg, order):
-    return dataclasses.replace(cfg, descending=(order == "desc"))
-
-
-def make_keys(dtype, n, rng, *, signed_zero=True):
-    """Adversarial-ish keys: full-range ints / floats spiked with the
-    special values (NaN always np.nan — see module docstring)."""
-    if dtype == "int32":
-        return rng.integers(-(2**31), 2**31 - 1, n).astype(np.int32)
-    if dtype == "uint32":
-        return rng.integers(0, 2**32, n, dtype=np.uint64).astype(np.uint32)
-    if dtype == "int64":
-        return rng.integers(-(2**63), 2**63 - 1, n, dtype=np.int64)
-    if dtype == "uint64":
-        return rng.integers(0, 2**64, n, dtype=np.uint64)
-    if dtype == "bool":
-        return rng.integers(0, 2, n).astype(bool)
-    # bfloat16 is generated as float32 (specials included below) and cast
-    # at the jnp boundary by make_jnp_keys — NaN/±inf/±0.0 are exact in
-    # bf16 and finite normals round to valid bf16 ties.
-    ftype = np.float64 if dtype == "float64" else np.float32
-    big = 1e300 if dtype == "float64" else 1e30
-    x = (rng.normal(size=n) * rng.choice([1.0 / big, 1.0, big], n)).astype(
-        ftype
-    )
-    specials = [np.nan, np.inf, -np.inf, 0.0] + ([-0.0] if signed_zero else [])
-    idx = rng.integers(0, n, min(n, 25))
-    x[idx] = np.asarray(specials, ftype)[
-        rng.integers(0, len(specials), len(idx))
-    ]
-    return x
-
-
-def npc(a):
-    """numpy view for comparisons: bfloat16 -> float32 (numpy's NaN-aware
-    assert helpers don't understand ml_dtypes scalars; the f32 embedding
-    is exact, so equality semantics are unchanged)."""
-    a = np.asarray(a)
-    if a.dtype == jnp.bfloat16:
-        return a.astype(np.float32)
-    return a
-
-
-def make_jnp_keys(dtype, n, rng, *, signed_zero=True):
-    """jnp array of ``dtype`` (inside the right x64 context)."""
-    x = jnp.asarray(make_keys(dtype, n, rng, signed_zero=signed_zero))
-    if dtype == "bfloat16":
-        x = x.astype(jnp.bfloat16)
-    return x
+from conformance_cases import (
+    ALL_DTYPES,
+    CELLS,
+    DTYPES,
+    ORDERS,
+    SIZES,
+    dtype_ctx,
+    make_jnp_keys,
+    make_keys,
+    npc,
+    order_cfg,
+)
+from repro.core import bucket_sort
 
 
 @pytest.mark.parametrize("cfg", CELLS)
@@ -141,36 +67,6 @@ def test_argsort_matches_jnp(rng, cfg, dtype, n):
 @pytest.mark.parametrize("cfg", CELLS)
 @pytest.mark.parametrize("dtype", ALL_DTYPES)
 @pytest.mark.parametrize("order", ORDERS)
-@pytest.mark.parametrize("n", [5, 700])  # direct path + bucket round
-def test_sort_all_dtypes_both_orders(rng, cfg, dtype, order, n):
-    """The key-codec matrix: every codec dtype, ascending and
-    descending, vs the jnp.sort oracle (values; NaN/±inf/-0.0 in)."""
-    desc = order == "desc"
-    with dtype_ctx(dtype):
-        x = make_jnp_keys(dtype, n, rng)
-        got = npc(bucket_sort.sort(x, order_cfg(cfg, order)))
-        want = npc(jnp.sort(x, descending=desc))
-    np.testing.assert_array_equal(got, want)
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-@pytest.mark.parametrize("dtype", ALL_DTYPES)
-@pytest.mark.parametrize("order", ORDERS)
-@pytest.mark.parametrize("n", [5, 700])
-def test_argsort_all_dtypes_both_orders(rng, cfg, dtype, order, n):
-    """Exact stable permutations for the full codec matrix (signed
-    zeros dropped — see module docstring)."""
-    desc = order == "desc"
-    with dtype_ctx(dtype):
-        x = make_jnp_keys(dtype, n, rng, signed_zero=False)
-        got = np.asarray(bucket_sort.argsort(x, order_cfg(cfg, order)))
-        want = np.asarray(jnp.argsort(x, stable=True, descending=desc))
-    np.testing.assert_array_equal(got, want)
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-@pytest.mark.parametrize("dtype", ALL_DTYPES)
-@pytest.mark.parametrize("order", ORDERS)
 def test_sort_kv_matches_jnp_permutation(rng, cfg, dtype, order):
     n = 700  # crosses both cells' direct_max
     desc = order == "desc"
@@ -184,165 +80,6 @@ def test_sort_kv_matches_jnp_permutation(rng, cfg, dtype, order):
         got_k, got_v = npc(sk), np.asarray(sv)
     np.testing.assert_array_equal(got_k, want_k)
     np.testing.assert_array_equal(got_v, vals[perm])
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-@pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("length", [40, 700])  # direct path + bucket round
-def test_batched_matches_jnp_rows(rng, cfg, dtype, length):
-    b = 5  # odd batch: exercises the row_pad path on pallas cells
-    x = np.stack([make_keys(dtype, length, rng, signed_zero=False)
-                  for _ in range(b)])
-    xj = jnp.asarray(x)
-    got = np.asarray(bucket_sort.sort_batched(xj, cfg))
-    np.testing.assert_array_equal(got, np.asarray(jnp.sort(xj, axis=-1)))
-    gotp = np.asarray(bucket_sort.argsort_batched(xj, cfg))
-    np.testing.assert_array_equal(
-        gotp, np.asarray(jnp.argsort(xj, axis=-1, stable=True))
-    )
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-@pytest.mark.parametrize("dtype", ALL_DTYPES)
-@pytest.mark.parametrize("order", ORDERS)
-def test_batched_all_dtypes_both_orders(rng, cfg, dtype, order):
-    """sort_batched/argsort_batched over the full codec matrix."""
-    b, length = 5, 700
-    desc = order == "desc"
-    with dtype_ctx(dtype):
-        x = jnp.stack([make_jnp_keys(dtype, length, rng, signed_zero=False)
-                       for _ in range(b)])
-        c = order_cfg(cfg, order)
-        got = npc(bucket_sort.sort_batched(x, c))
-        want = npc(jnp.sort(x, axis=-1, descending=desc))
-        gotp = np.asarray(bucket_sort.argsort_batched(x, c))
-        wantp = np.asarray(
-            jnp.argsort(x, axis=-1, stable=True, descending=desc)
-        )
-    np.testing.assert_array_equal(got, want)
-    np.testing.assert_array_equal(gotp, wantp)
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-def test_sort_kv_batched_matches_jnp_rows(rng, cfg):
-    b, length = 4, 700
-    x = rng.integers(0, 50, (b, length)).astype(np.int32)  # heavy ties
-    vals = rng.normal(size=(b, length, 2)).astype(np.float32)
-    sk, sv = bucket_sort.sort_kv_batched(
-        jnp.asarray(x), jnp.asarray(vals), cfg
-    )
-    perm = np.argsort(x, axis=-1, kind="stable")
-    np.testing.assert_array_equal(np.asarray(sk), np.sort(x, axis=-1))
-    np.testing.assert_array_equal(
-        np.asarray(sv), np.take_along_axis(vals, perm[:, :, None], axis=1)
-    )
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-@pytest.mark.parametrize("dtype", DTYPES)
-def test_segmented_matches_jnp_per_segment(rng, cfg, dtype):
-    n = 1200
-    x = make_keys(dtype, n, rng, signed_zero=False)
-    # empty, single-element, and > direct_max segments
-    off = [0, 0, 1, 5, 600, 600, 900, n]
-    xj = jnp.asarray(x)
-    got = np.asarray(bucket_sort.segment_sort(xj, off, cfg))
-    gotp = np.asarray(bucket_sort.segment_argsort(xj, off, cfg))
-    for lo, hi in zip(off, off[1:]):
-        seg = jnp.asarray(x[lo:hi])
-        np.testing.assert_array_equal(got[lo:hi], np.asarray(jnp.sort(seg)))
-        np.testing.assert_array_equal(
-            gotp[lo:hi], lo + np.asarray(jnp.argsort(seg, stable=True))
-        )
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-@pytest.mark.parametrize("dtype", ["int64", "float64", "bool"])
-@pytest.mark.parametrize("order", ORDERS)
-def test_segmented_wide_dtypes_both_orders(rng, cfg, dtype, order):
-    """segment_sort/segment_argsort over codec satellites + descending."""
-    n = 1200
-    desc = order == "desc"
-    off = [0, 0, 1, 5, 600, 600, 900, n]
-    with dtype_ctx(dtype):
-        x = make_jnp_keys(dtype, n, rng, signed_zero=False)
-        c = order_cfg(cfg, order)
-        got = np.asarray(bucket_sort.segment_sort(x, off, c))
-        gotp = np.asarray(bucket_sort.segment_argsort(x, off, c))
-        want, wantp = [], []
-        for lo, hi in zip(off, off[1:]):
-            want.append(np.asarray(jnp.sort(x[lo:hi], descending=desc)))
-            wantp.append(lo + np.asarray(
-                jnp.argsort(x[lo:hi], stable=True, descending=desc)
-            ))
-    for (lo, hi), w, wp in zip(zip(off, off[1:]), want, wantp):
-        np.testing.assert_array_equal(got[lo:hi], w)
-        np.testing.assert_array_equal(gotp[lo:hi], wp)
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-@pytest.mark.parametrize("dtype", ["int32", "float32"])
-@pytest.mark.parametrize("n", [300, 1500])  # direct path + partial round
-def test_topk_matches_lax(rng, cfg, dtype, n):
-    k = 16
-    if dtype == "int32":
-        x = rng.integers(-(2**31), 2**31 - 1, n).astype(np.int32)
-    else:
-        x = rng.normal(size=n).astype(np.float32)
-        x[rng.integers(0, n, 5)] = np.asarray(
-            [np.inf, -np.inf, 0.0, 1.0, -1.0], np.float32
-        )
-    tv, ti = partial_sort.topk(jnp.asarray(x), k, cfg)
-    lv, li = jax.lax.top_k(jnp.asarray(x), k)
-    np.testing.assert_array_equal(np.asarray(tv), np.asarray(lv))
-    np.testing.assert_array_equal(np.asarray(ti), np.asarray(li))
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-@pytest.mark.parametrize("dtype", WIDE_DTYPES)
-@pytest.mark.parametrize("n", [300, 1500])  # direct path + partial round
-def test_topk_wide_dtypes_matches_lax(rng, cfg, dtype, n):
-    """topk over the codec satellites (two-word 64-bit, bf16, bool —
-    bool is ALL ties: pure index-tiebreak conformance)."""
-    k = 16
-    with dtype_ctx(dtype):
-        x = make_jnp_keys(dtype, n, rng, signed_zero=False)
-        tv, ti = partial_sort.topk(x, k, cfg)
-        lv, li = jax.lax.top_k(x, k)
-        got = (npc(tv), np.asarray(ti))
-        want = (npc(lv), np.asarray(li))
-    np.testing.assert_array_equal(got[0], want[0])
-    np.testing.assert_array_equal(got[1], want[1])
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-@pytest.mark.parametrize("dtype", ["int32", "float32"])
-@pytest.mark.parametrize("n", [300, 1500])  # direct path + partial round
-def test_topk_batched_matches_lax(rng, cfg, dtype, n):
-    b, k = 5, 16
-    if dtype == "int32":
-        x = rng.integers(0, 40, (b, n)).astype(np.int32)  # heavy ties
-    else:
-        x = rng.normal(size=(b, n)).astype(np.float32)
-    tv, ti = partial_sort.topk_batched(jnp.asarray(x), k, cfg)
-    lv, li = jax.lax.top_k(jnp.asarray(x), k)
-    np.testing.assert_array_equal(np.asarray(tv), np.asarray(lv))
-    np.testing.assert_array_equal(np.asarray(ti), np.asarray(li))
-
-
-@pytest.mark.parametrize("cfg", CELLS)
-@pytest.mark.parametrize("dtype", WIDE_DTYPES)
-def test_topk_batched_wide_dtypes_matches_lax(rng, cfg, dtype):
-    b, k, n = 5, 16, 1500
-    with dtype_ctx(dtype):
-        x = jnp.stack([make_jnp_keys(dtype, n, rng, signed_zero=False)
-                       for _ in range(b)])
-        tv, ti = partial_sort.topk_batched(x, k, cfg)
-        lv, li = jax.lax.top_k(x, k)
-        got = (npc(tv), np.asarray(ti))
-        want = (npc(lv), np.asarray(li))
-    np.testing.assert_array_equal(got[0], want[0])
-    np.testing.assert_array_equal(got[1], want[1])
 
 
 # ----------------------------------------------------------------------

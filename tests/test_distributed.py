@@ -216,7 +216,7 @@ def test_make_sharded_sort_validation_messages_2dev():
         expect("must be divisible by the axis device count",
                lambda: make_sharded_sort(mesh, "data", 1001, cfg))
         expect("exceeds the int32 payload budget",
-               lambda: make_sharded_sort(mesh, "data", 2**27, cfg))
+               lambda: make_sharded_sort(mesh, "data", 2**27 + 2, cfg))
         expect("oversample must be a power of two",
                lambda: make_sharded_sort(mesh, "data", 2048, cfg, 5))
         expect("pair_align must be a power of two >= 8",
@@ -319,8 +319,7 @@ def test_compressed_allreduce_8dev():
             mean, res = allreduce_compressed({"w": g}, "data")
             exact = jax.lax.pmean(g, "data")
             return mean["w"][None], res["w"][None], exact[None]
-        from repro.compat import shard_map
-        f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("data"),),
+        f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("data"),),
                     out_specs=(P("data"), P("data"), P("data"))))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.normal(size=(8, 1024)).astype(np.float32))

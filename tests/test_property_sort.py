@@ -130,7 +130,7 @@ def _codec_bijection_case(x, descending):
 @settings(max_examples=25, deadline=None)
 @given(int64s, st.booleans())
 def test_codec_int64_bijection_preserves_order(xs, descending):
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         _codec_bijection_case(jnp.asarray(np.asarray(xs, np.int64)),
                               descending)
 
@@ -139,11 +139,13 @@ def test_codec_int64_bijection_preserves_order(xs, descending):
 @given(floats64, st.booleans())
 def test_codec_float64_bijection_preserves_order(xs, descending):
     """Full float64 range incl. NaN/±inf; signed zeros normalized to
-    +0.0 (our total order ranks -0.0 < +0.0 strictly, numpy ties them —
-    the conformance suite pins the value-level agreement)."""
+    +0.0 and NaNs to +NaN (our total order ranks -0.0 < +0.0 strictly
+    and -NaN first, numpy ties the zeros and puts every NaN last — the
+    conformance suite pins the value-level agreement)."""
     x = np.asarray(xs, np.float64)
     x[x == 0.0] = 0.0
-    with jax.experimental.enable_x64():
+    x[np.isnan(x)] = np.nan
+    with jax.enable_x64(True):
         _codec_bijection_case(jnp.asarray(x), descending)
 
 

@@ -43,7 +43,7 @@ DTYPES = ["int32", "uint32", "int64", "float32"]
 
 def dtype_ctx(dtype):
     if dtype == "int64":
-        return jax.experimental.enable_x64()
+        return jax.enable_x64(True)
     return contextlib.nullcontext()
 
 
