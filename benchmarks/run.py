@@ -55,7 +55,6 @@ def main() -> None:
         moe_dispatch,
         sample_size_sweep,
         sort_throughput,
-        step_breakdown,
         strategies,
         topk_partial,
     )
@@ -67,8 +66,6 @@ def main() -> None:
         "sample_size_sweep": lambda: sample_size_sweep.run(
             n=131072 if quick else 524288,
             svals=(16, 64) if quick else (8, 16, 32, 64, 128)),
-        "step_breakdown": lambda: step_breakdown.run(
-            n=262144 if quick else 1048576),
         "distribution_robustness": lambda: distribution_robustness.run(
             n=65536 if quick else 262144),
         "moe_dispatch": lambda: moe_dispatch.run(

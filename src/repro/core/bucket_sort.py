@@ -18,6 +18,24 @@ Single-device deterministic sample sort.  The paper's nine steps map to
                                          gather-based compaction back to
                                          dense rows
 
+NAMES IN A TRACE: every executor node opens ``jax.named_scope`` of its
+level (``sort.level0`` for the root, d + 1 for a sample or bucket
+child), and inside it one scope per step, so a compiled op's
+``op_name`` metadata reads ``jit(...)/sort.level<d>/.../sort.<step>/...``:
+
+  sort.local_sort   steps 1-3 (and a direct node's single-tile sort)
+  sort.splitters    step 5
+  sort.partition    steps 6-7 (splitter partition, cumsums, fills)
+  sort.relocate     step 8
+  sort.compact      step 9's compaction
+  sort.pad          column padding of a node
+  (steps 4 and 9's recursion are the child node's own level scope)
+
+The Pallas kernels are named ``bitonic_tile_sort`` and
+``splitter_partition``.  On the host, each public entry opens the span
+``sort.<entry>`` and inside it ``sort.plan``, ``sort.encode``, one
+``sort.launch`` per attempt and ``sort.decode`` (``core/telemetry.py``).
+
 PLANNER / EXECUTOR SPLIT (DESIGN.md §7): deterministic regular
 sampling makes the whole multi-level schedule — recursion levels,
 per-level rows x tile geometry, s_round, capacities, pad budgets,
@@ -110,7 +128,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import guard
+from repro.core import guard, telemetry
 from repro.core import plan as plan_mod
 from repro.core.key_codec import codec_for
 from repro.core.plan import LevelPlan, SortPlan, build_plan
@@ -120,17 +138,25 @@ from repro.kernels import ops
 _MAXU = jnp.uint32(0xFFFFFFFF)
 _INT_MAX = 2**31 - 1
 
-# Python-side retrace counter: incremented once per TRACE of the jit'd
-# canonical entry (not per call).  ``tests/test_plan.py`` asserts the
-# compile-count discipline with it: same (shape, dtype, cfg) => one
-# trace; a plan-cache hit => zero new traces.
-_TRACE_COUNT = 0
-
 
 def trace_count() -> int:
     """Number of times the canonical packed entry has been TRACED in
-    this process (a retrace/compile-discipline counter for tests)."""
-    return _TRACE_COUNT
+    this process (the ``sort.traces`` counter).  ``tests/test_plan.py``
+    asserts the compile-count discipline with it: same (shape, dtype,
+    cfg) => one trace; a plan-cache hit => zero new traces."""
+    return telemetry.counts().get("sort.traces", 0)
+
+
+def _entry_span(fn):
+    """Run a public entry inside the host span ``sort.<its name>``."""
+    name = f"sort.{fn.__name__}"
+
+    @functools.wraps(fn)
+    def entry(*args, **kwargs):
+        with telemetry.span(name):
+            return fn(*args, **kwargs)
+
+    return entry
 
 
 def _pad_cols(kw, vals, new_len, pad_base):
@@ -151,12 +177,13 @@ def _pad_cols(kw, vals, new_len, pad_base):
     extra = new_len - length
     if extra == 0:
         return kw, vals, pad_base
-    pk = jnp.full((r, extra), _MAXU, jnp.uint32)
-    pv = jnp.int32(pad_base) + jax.lax.broadcasted_iota(
-        jnp.int32, (r, extra), 1
-    )
-    kw = tuple(jnp.concatenate([w, pk], axis=1) for w in kw)
-    vals = jnp.concatenate([vals, pv], axis=1)
+    with jax.named_scope("sort.pad"):
+        pk = jnp.full((r, extra), _MAXU, jnp.uint32)
+        pv = jnp.int32(pad_base) + jax.lax.broadcasted_iota(
+            jnp.int32, (r, extra), 1
+        )
+        kw = tuple(jnp.concatenate([w, pk], axis=1) for w in kw)
+        vals = jnp.concatenate([vals, pv], axis=1)
     return kw, vals, pad_base + extra
 
 
@@ -166,12 +193,13 @@ def _direct_sort(kw, vals, node: LevelPlan, impl, interpret, pad_base):
     local-sort strategy are plan-carried (DESIGN.md §8)."""
     length = kw[0].shape[1]
     kw, vals, pad_base = _pad_cols(kw, vals, node.lp, pad_base)
-    sk, sv = ops.sort_tiles(
-        kw, vals, impl=impl, interpret=interpret,
-        block_rows=node.block_rows, strategy=node.strategy,
-        radix_bits=node.radix_bits, merge_run=node.merge_run,
-    )
-    return tuple(w[:, :length] for w in sk), sv[:, :length], pad_base
+    with jax.named_scope("sort.local_sort"):
+        sk, sv = ops.sort_tiles(
+            kw, vals, impl=impl, interpret=interpret,
+            block_rows=node.block_rows, strategy=node.strategy,
+            radix_bits=node.radix_bits, merge_run=node.merge_run,
+        )
+        return tuple(w[:, :length] for w in sk), sv[:, :length], pad_base
 
 
 def _chunk_values(offsets, values, length: int):
@@ -322,7 +350,7 @@ def _compact_scatter(ckw, cv, totals, r, s_round, cap, lp):
 
 
 def _run_node(kw, vals, node: LevelPlan, impl: str, interpret: bool,
-              pad_base: int, stats: list | None):
+              pad_base: int, stats: list | None, depth: int = 0):
     """EXECUTOR: sort each row of (rows, L) canonical key words / int32
     payloads by walking one node of the plan tree.
 
@@ -335,6 +363,8 @@ def _run_node(kw, vals, node: LevelPlan, impl: str, interpret: bool,
         kw: tuple of (rows, L) uint32 key-word arrays (msw first).
         vals: (rows, L) int32 payloads, unique per row.
         node: the plan node matching (rows, L) exactly.
+        depth: the node's level in the plan tree (0 at the root), which
+            names its ``sort.level<depth>`` scope.
     Returns:
         (sorted kw, sorted vals, pad_base) with dense sorted rows of the
         input shape.  Static walk: every shape is trace-time known;
@@ -346,110 +376,131 @@ def _run_node(kw, vals, node: LevelPlan, impl: str, interpret: bool,
         f"plan/data mismatch: data {(r, length)} vs plan node "
         f"{(node.rows, node.length)}"
     )
-    if node.kind == "direct":
-        return _direct_sort(kw, vals, node, impl, interpret, pad_base)
+    with jax.named_scope(f"sort.level{depth}"):
+        if node.kind == "direct":
+            return _direct_sort(kw, vals, node, impl, interpret, pad_base)
+        return _bucket_round(kw, vals, node, impl, interpret, pad_base,
+                             stats, depth)
 
+
+def _bucket_round(kw, vals, node: LevelPlan, impl: str, interpret: bool,
+                  pad_base: int, stats: list | None, depth: int):
+    """Steps 1-9 of one bucket node (see :func:`_run_node`)."""
+    r, length = kw[0].shape
     t, sper, lp, m = node.tile, node.s, node.lp, node.m
     s_round, cap = node.s_round, node.cap
     kw, vals, pad_base = _pad_cols(kw, vals, lp, pad_base)
 
     # Steps 1-3: row-blocked local tile sort, sample extraction fused in.
-    tkw = tuple(w.reshape(r * m, t) for w in kw)
-    tv = vals.reshape(r * m, t)
-    if node.fuse_sampling:
-        tkw, tv, samp_kw, samp_v = ops.sort_tiles_sample(
-            tkw, tv, num_samples=sper, impl=impl,
-            interpret=interpret, block_rows=node.block_rows,
-            strategy=node.strategy, radix_bits=node.radix_bits,
-            merge_run=node.merge_run,
-        )
-        samples_kw = tuple(w.reshape(r, m * sper) for w in samp_kw)
-        samples_v = samp_v.reshape(r, m * sper)
-    else:
-        tkw, tv = ops.sort_tiles(
-            tkw, tv, impl=impl, interpret=interpret,
-            block_rows=node.block_rows, strategy=node.strategy,
-            radix_bits=node.radix_bits, merge_run=node.merge_run,
-        )
-        samp_idx = (jnp.arange(1, sper + 1, dtype=jnp.int32) * (t // sper)) - 1
-        samples_kw = tuple(w[:, samp_idx].reshape(r, m * sper) for w in tkw)
-        samples_v = tv[:, samp_idx].reshape(r, m * sper)
+    with jax.named_scope("sort.local_sort"):
+        tkw = tuple(w.reshape(r * m, t) for w in kw)
+        tv = vals.reshape(r * m, t)
+        if node.fuse_sampling:
+            tkw, tv, samp_kw, samp_v = ops.sort_tiles_sample(
+                tkw, tv, num_samples=sper, impl=impl,
+                interpret=interpret, block_rows=node.block_rows,
+                strategy=node.strategy, radix_bits=node.radix_bits,
+                merge_run=node.merge_run,
+            )
+            samples_kw = tuple(w.reshape(r, m * sper) for w in samp_kw)
+            samples_v = samp_v.reshape(r, m * sper)
+        else:
+            tkw, tv = ops.sort_tiles(
+                tkw, tv, impl=impl, interpret=interpret,
+                block_rows=node.block_rows, strategy=node.strategy,
+                radix_bits=node.radix_bits, merge_run=node.merge_run,
+            )
+            samp_idx = (
+                jnp.arange(1, sper + 1, dtype=jnp.int32) * (t // sper)
+            ) - 1
+            samples_kw = tuple(
+                w[:, samp_idx].reshape(r, m * sper) for w in tkw
+            )
+            samples_v = tv[:, samp_idx].reshape(r, m * sper)
 
     # Step 4: sort all samples (recursive; sample array is L*s/T << L).
     sskw, ssv, pad_base = _run_node(
         samples_kw, samples_v, node.sample_plan, impl, interpret, pad_base,
-        None,
+        None, depth + 1,
     )
 
     # Step 5: s_round - 1 equidistant global splitters.
-    total_samples = m * sper
-    sp_idx = (jnp.arange(1, s_round, dtype=jnp.int32) * total_samples) // s_round
-    spkw = tuple(w[:, sp_idx] for w in sskw)  # (r, s_round-1) each
-    spv = ssv[:, sp_idx]
+    with jax.named_scope("sort.splitters"):
+        total_samples = m * sper
+        sp_idx = (
+            jnp.arange(1, s_round, dtype=jnp.int32) * total_samples
+        ) // s_round
+        spkw = tuple(w[:, sp_idx] for w in sskw)  # (r, s_round-1) each
+        spv = ssv[:, sp_idx]
 
     # Steps 6-7: splitter ranks + per-tile bucket counts (fused epilogue),
     # then the column-major prefix sums over (rows, m, s_round).
-    spkw_t = tuple(jnp.repeat(w, m, axis=0) for w in spkw)  # (r*m, s_round-1)
-    spv_t = jnp.repeat(spv, m, axis=0)
-    if node.fuse_ranking:
-        ranks, counts2 = ops.splitter_partition(
-            tkw, tv, spkw_t, spv_t, impl=impl, interpret=interpret,
-            block_rows=node.part_block_rows,
-        )  # ranks (r*m, s_round-1); counts2 (r*m, s_round)
-    else:
-        ranks = ops.splitter_ranks(
-            tkw, tv, spkw_t, spv_t, impl=impl, interpret=interpret
-        )  # (r*m, s_round-1), values in [0, T]
-        ends = jnp.concatenate(
-            [ranks, jnp.full((r * m, 1), t, jnp.int32)], axis=1
-        )
-        counts2 = ends - jnp.concatenate(
+    with jax.named_scope("sort.partition"):
+        spkw_t = tuple(jnp.repeat(w, m, axis=0) for w in spkw)  # (r*m, s_round-1)
+        spv_t = jnp.repeat(spv, m, axis=0)
+        if node.fuse_ranking:
+            ranks, counts2 = ops.splitter_partition(
+                tkw, tv, spkw_t, spv_t, impl=impl, interpret=interpret,
+                block_rows=node.part_block_rows,
+            )  # ranks (r*m, s_round-1); counts2 (r*m, s_round)
+        else:
+            ranks = ops.splitter_ranks(
+                tkw, tv, spkw_t, spv_t, impl=impl, interpret=interpret
+            )  # (r*m, s_round-1), values in [0, T]
+            ends = jnp.concatenate(
+                [ranks, jnp.full((r * m, 1), t, jnp.int32)], axis=1
+            )
+            counts2 = ends - jnp.concatenate(
+                [jnp.zeros((r * m, 1), jnp.int32), ranks], axis=1
+            )
+        starts = jnp.concatenate(
             [jnp.zeros((r * m, 1), jnp.int32), ranks], axis=1
-        )
-    starts = jnp.concatenate(
-        [jnp.zeros((r * m, 1), jnp.int32), ranks], axis=1
-    )  # (r*m, s_round): start of bucket j within tile i
-    counts = counts2.reshape(r, m, s_round)
-    # offset of tile i's chunk within bucket j of its row (exclusive cumsum):
-    tile_off = jnp.cumsum(counts, axis=1, dtype=jnp.int32) - counts  # (r, m, s_round)
-    totals = counts.sum(axis=1, dtype=jnp.int32)  # (r, s_round) true bucket fills
+        )  # (r*m, s_round): start of bucket j within tile i
+        counts = counts2.reshape(r, m, s_round)
+        # offset of tile i's chunk within bucket j of its row (exclusive cumsum):
+        tile_off = jnp.cumsum(counts, axis=1, dtype=jnp.int32) - counts  # (r, m, s_round)
+        totals = counts.sum(axis=1, dtype=jnp.int32)  # (r, s_round) true bucket fills
+        if stats is not None:
+            stats.append(
+                dict(
+                    level_len=lp,
+                    rows=r,
+                    s_round=s_round,
+                    capacity=cap,
+                    totals=totals,
+                    # every bucket's elements sit at 0..fill-1 of their row
+                    max_within=jnp.max(totals) - 1,
+                )
+            )
 
     # Step 8: relocation into the dense (r*s_round, cap) bucket array.
-    if node.relocation == "gather":
-        bkw, bv = _relocate_gather(
-            tkw, tv, starts, tile_off, totals, r, m, s_round, t, cap, pad_base
-        )
-    else:
-        bkw, bv = _relocate_scatter(
-            tkw, tv, ranks, starts, tile_off, r, m, s_round, t, cap, pad_base
-        )
-    pad_base += cap
-
-    if stats is not None:
-        stats.append(
-            dict(
-                level_len=lp,
-                rows=r,
-                s_round=s_round,
-                capacity=cap,
-                totals=totals,
-                # every bucket's elements sit at 0..fill-1 of their row
-                max_within=jnp.max(totals) - 1,
+    with jax.named_scope("sort.relocate"):
+        if node.relocation == "gather":
+            bkw, bv = _relocate_gather(
+                tkw, tv, starts, tile_off, totals, r, m, s_round, t, cap,
+                pad_base,
             )
-        )
+        else:
+            bkw, bv = _relocate_scatter(
+                tkw, tv, ranks, starts, tile_off, r, m, s_round, t, cap,
+                pad_base,
+            )
+    pad_base += cap
 
     # Step 9: sort every bucket row (recursion), then compact to dense rows.
     ckw, cv, pad_base = _run_node(
-        bkw, bv, node.bucket_plan, impl, interpret, pad_base, stats
+        bkw, bv, node.bucket_plan, impl, interpret, pad_base, stats,
+        depth + 1,
     )
 
     # Compaction: first totals[q, j] entries of bucket row (q, j) are exactly
     # the elements this level relocated there (fresh pads sort after them).
-    if node.relocation == "gather":
-        okw, ov = _compact_gather(ckw, cv, totals, r, s_round, cap, lp)
-    else:
-        okw, ov = _compact_scatter(ckw, cv, totals, r, s_round, cap, lp)
-    return tuple(w[:, :length] for w in okw), ov[:, :length], pad_base
+    with jax.named_scope("sort.compact"):
+        if node.relocation == "gather":
+            okw, ov = _compact_gather(ckw, cv, totals, r, s_round, cap, lp)
+        else:
+            okw, ov = _compact_scatter(ckw, cv, totals, r, s_round, cap, lp)
+        return tuple(w[:, :length] for w in okw), ov[:, :length], pad_base
 
 
 def _sort_rows(kw, vals, cfg: SortConfig, pad_base: int, stats: list | None):
@@ -485,8 +536,7 @@ def _sort_canonical_packed(keys_words, vals, plan: SortPlan, pad_base0: int,
     Returns:
         (sorted words, sorted vals[, stats]).
     """
-    global _TRACE_COUNT
-    _TRACE_COUNT += 1  # python side effect: runs once per TRACE
+    telemetry.count("sort.traces")  # python side effect: once per TRACE
     stats: list | None = [] if with_stats else None
     kw = tuple(keys_words)
     skw, sv, _ = _run_node(
@@ -546,6 +596,7 @@ def _fallback_plan(plan: SortPlan) -> SortPlan | None:
 
 
 def _execute_packed(kw, vals, plan: SortPlan, pad_base0: int, *,
+                    n_keys: int,
                     check: str = "off", degrade: bool = True,
                     with_stats: bool = False):
     """Guarded, degrading funnel every packed entry point runs through.
@@ -566,7 +617,12 @@ def _execute_packed(kw, vals, plan: SortPlan, pad_base0: int, *,
       3. the ``jax.lax.sort`` reference (no plan machinery at all).
 
     Each step re-runs the checks; events land in
-    ``guard.degradation_log()``.  Any other error — a kernel Mosaic
+    ``guard.degradation_log()``.  The call is counted once: ``n_keys``
+    real keys (``sort.keys``; padded rows and columns left out) and the
+    plan's ``sort.moved_elements``.  Each attempt opens its own
+    ``sort.launch`` span.  Under an outer ``jax.jit`` this body runs
+    once per trace, so the counters and spans then count traces, not
+    calls.  Any other error — a kernel Mosaic
     refuses, a lowering error — propagates: the chain never hides a run
     that left its device path.  ``degrade=False`` (the explicit-plan
     API) propagates the structured error too.  Returns
@@ -576,9 +632,12 @@ def _execute_packed(kw, vals, plan: SortPlan, pad_base0: int, *,
     guard.validate_check(check)
     check_pad_budget(plan, pad_base0)
     want_stats = with_stats or check != "off"
+    telemetry.count("sort.keys", n_keys)
+    telemetry.count("sort.moved_elements", plan.moved_elements)
 
     def run(p: SortPlan):
-        out = _sort_canonical_packed(kw, vals, p, pad_base0, want_stats)
+        with telemetry.span("sort.launch"):
+            out = _sort_canonical_packed(kw, vals, p, pad_base0, want_stats)
         skw, sv, stats = out if want_stats else (out[0], out[1], [])
         if check != "off":
             guard.check_bounds(p, stats)
@@ -605,7 +664,8 @@ def _execute_packed(kw, vals, plan: SortPlan, pad_base0: int, *,
             guard.record_degradation(
                 guard.plan_site(plan), "fallback",
                 "plan execution", "jax.lax.sort reference", e1)
-            skw, sv = _reference_sort_packed(kw, vals)
+            with telemetry.span("sort.launch"):
+                skw, sv = _reference_sort_packed(kw, vals)
             stats = []
             if check == "full":
                 guard.check_full(plan, kw, vals, skw, sv)
@@ -638,24 +698,25 @@ def check_pad_budget(plan: SortPlan, pad_base0: int) -> None:
         )
 
 
-def _sort_canonical_rows(kw, plan: SortPlan, with_stats: bool = False,
-                         check: str = "off"):
-    """(B, L) canonical sort with payload = original index within the row."""
-    b, n = kw[0].shape
-    vals = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :], (b, n))
-    return _execute_packed(kw, vals, plan, n, check=check,
-                           with_stats=with_stats)
+def _resolve_1d(keys, cfg: SortConfig):
+    """The codec and plan of a 1-D entry, inside the ``sort.plan`` span."""
+    with telemetry.span("sort.plan"):
+        codec = codec_for(keys.dtype, cfg.descending)
+        return codec, resolve_plan(keys.shape[0], keys.dtype, cfg)
 
 
-def _sort_canonical(kw, plan: SortPlan, with_stats: bool = False,
+def _sort_canonical(keys, codec, plan: SortPlan, with_stats: bool = False,
                     check: str = "off"):
-    """1-D canonical entry (single logical row of the batched path)."""
-    out = _sort_canonical_rows(tuple(w[None, :] for w in kw), plan,
-                               with_stats, check)
-    skw = tuple(w[0] for w in out[0])
-    if with_stats:
-        return skw, out[1][0], out[2]
-    return skw, out[1][0]
+    """1-D canonical entry: ``keys`` encoded as the single row of the
+    batched path, payload = original index.  Returns the (1, n) packed
+    (words, perm[, stats]); the caller decodes."""
+    n = keys.shape[0]
+    with telemetry.span("sort.encode"):
+        kw = tuple(w[None, :] for w in codec.encode(keys))
+        vals = jnp.broadcast_to(
+            jnp.arange(n, dtype=jnp.int32)[None, :], (1, n))
+    return _execute_packed(kw, vals, plan, n, n_keys=n, check=check,
+                           with_stats=with_stats)
 
 
 def _pad_rows(kw, vals, plan: SortPlan):
@@ -685,6 +746,7 @@ def _pad_rows(kw, vals, plan: SortPlan):
 # ----------------------------------------------------------------------
 
 
+@_entry_span
 def sort(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG) -> jax.Array:
     """Deterministic sample sort of a 1-D array (stable, total order).
 
@@ -706,12 +768,13 @@ def sort(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG) -> jax.Array:
     """
     if keys.shape[0] <= 1:
         return keys
-    codec = codec_for(keys.dtype, cfg.descending)
-    plan = resolve_plan(keys.shape[0], keys.dtype, cfg)
-    su, _ = _sort_canonical(codec.encode(keys), plan, check=cfg.check)
-    return codec.decode(su)
+    codec, plan = _resolve_1d(keys, cfg)
+    su, _ = _sort_canonical(keys, codec, plan, check=cfg.check)
+    with telemetry.span("sort.decode"):
+        return codec.decode(tuple(w[0] for w in su))
 
 
+@_entry_span
 def argsort(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG) -> jax.Array:
     """Stable argsort via deterministic sample sort.
 
@@ -732,12 +795,13 @@ def argsort(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG) -> jax.Array:
     """
     if keys.shape[0] <= 1:
         return jnp.arange(keys.shape[0], dtype=jnp.int32)
-    codec = codec_for(keys.dtype, cfg.descending)
-    plan = resolve_plan(keys.shape[0], keys.dtype, cfg)
-    _, perm = _sort_canonical(codec.encode(keys), plan, check=cfg.check)
-    return perm
+    codec, plan = _resolve_1d(keys, cfg)
+    _, perm = _sort_canonical(keys, codec, plan, check=cfg.check)
+    with telemetry.span("sort.decode"):
+        return perm[0]
 
 
+@_entry_span
 def sort_kv(keys: jax.Array, values: jax.Array, cfg: SortConfig = DEFAULT_CONFIG):
     """Stable (keys, values) sort by keys.
 
@@ -752,12 +816,14 @@ def sort_kv(keys: jax.Array, values: jax.Array, cfg: SortConfig = DEFAULT_CONFIG
     n = keys.shape[0]
     if n <= 1:
         return keys, values
-    codec = codec_for(keys.dtype, cfg.descending)
-    plan = resolve_plan(n, keys.dtype, cfg)
-    su, perm = _sort_canonical(codec.encode(keys), plan, check=cfg.check)
-    return codec.decode(su), jnp.take(values, perm, axis=0)
+    codec, plan = _resolve_1d(keys, cfg)
+    su, perm = _sort_canonical(keys, codec, plan, check=cfg.check)
+    with telemetry.span("sort.decode"):
+        return (codec.decode(tuple(w[0] for w in su)),
+                jnp.take(values, perm[0], axis=0))
 
 
+@_entry_span
 def sort_with_stats(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG):
     """Sort + per-round stats (capacities, bucket fills) for bound tests.
 
@@ -773,14 +839,15 @@ def sort_with_stats(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG):
     n = keys.shape[0]
     if n <= 1:
         return keys, jnp.arange(n, dtype=jnp.int32), []
-    codec = codec_for(keys.dtype, cfg.descending)
-    plan = resolve_plan(n, keys.dtype, cfg)
+    codec, plan = _resolve_1d(keys, cfg)
     su, perm, stats = _sort_canonical(
-        codec.encode(keys), plan, with_stats=True, check=cfg.check
+        keys, codec, plan, with_stats=True, check=cfg.check
     )
-    return codec.decode(su), perm, stats
+    with telemetry.span("sort.decode"):
+        return codec.decode(tuple(w[0] for w in su)), perm[0], stats
 
 
+@_entry_span
 def sort_planned(keys: jax.Array, plan: SortPlan,
                  check: str = "off") -> jax.Array:
     """Sort with an EXPLICIT :class:`~repro.core.plan.SortPlan`.
@@ -826,22 +893,26 @@ def sort_planned(keys: jax.Array, plan: SortPlan,
     if plan.length <= 1:
         return keys
     codec = codec_for(keys.dtype, plan.descending)
-    if keys.ndim == 1:
-        kw1 = tuple(w[None, :] for w in codec.encode(keys))
-        vals = jnp.broadcast_to(
-            jnp.arange(plan.length, dtype=jnp.int32)[None, :],
-            (1, plan.length),
-        )
-        sk, _ = _execute_packed(kw1, vals, plan, plan.length,
-                                check=check, degrade=False)
-        return codec.decode(tuple(w[0] for w in sk))
-    vals = jnp.broadcast_to(
-        jnp.arange(plan.length, dtype=jnp.int32)[None, :], keys.shape
-    )
-    kw, vals = _pad_rows(codec.encode(keys), vals, plan)
+    with telemetry.span("sort.encode"):
+        if keys.ndim == 1:
+            kw = tuple(w[None, :] for w in codec.encode(keys))
+            vals = jnp.broadcast_to(
+                jnp.arange(plan.length, dtype=jnp.int32)[None, :],
+                (1, plan.length),
+            )
+        else:
+            vals = jnp.broadcast_to(
+                jnp.arange(plan.length, dtype=jnp.int32)[None, :],
+                keys.shape,
+            )
+            kw, vals = _pad_rows(codec.encode(keys), vals, plan)
     sk, _ = _execute_packed(kw, vals, plan, plan.length,
+                            n_keys=plan.rows * plan.length,
                             check=check, degrade=False)
-    return codec.decode(tuple(w[:plan.rows] for w in sk))
+    with telemetry.span("sort.decode"):
+        if keys.ndim == 1:
+            return codec.decode(tuple(w[0] for w in sk))
+        return codec.decode(tuple(w[:plan.rows] for w in sk))
 
 
 # ----------------------------------------------------------------------
@@ -854,17 +925,20 @@ def _batched_entry(keys, cfg: SortConfig):
     per-row index payloads, row_pad alignment.  Returns
     (codec, plan, kw, vals, b) — slice results [:b]."""
     b, length = keys.shape
-    codec = codec_for(keys.dtype, cfg.descending)
-    plan = resolve_plan(length, keys.dtype, cfg, rows=b, pad_rows=True)
-    kw, vals = _pad_rows(
-        codec.encode(keys),
-        jnp.broadcast_to(jnp.arange(length, dtype=jnp.int32)[None, :],
-                         (b, length)),
-        plan,
-    )
+    with telemetry.span("sort.plan"):
+        codec = codec_for(keys.dtype, cfg.descending)
+        plan = resolve_plan(length, keys.dtype, cfg, rows=b, pad_rows=True)
+    with telemetry.span("sort.encode"):
+        kw, vals = _pad_rows(
+            codec.encode(keys),
+            jnp.broadcast_to(jnp.arange(length, dtype=jnp.int32)[None, :],
+                             (b, length)),
+            plan,
+        )
     return codec, plan, kw, vals, b
 
 
+@_entry_span
 def sort_batched(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG) -> jax.Array:
     """Sort each row of a (B, L) array independently (stable).
 
@@ -883,10 +957,13 @@ def sort_batched(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG) -> jax.Array
     if b == 0 or length <= 1:
         return keys
     codec, plan, kw, vals, b = _batched_entry(keys, cfg)
-    sk, _ = _execute_packed(kw, vals, plan, length, check=cfg.check)
-    return codec.decode(tuple(w[:b] for w in sk))
+    sk, _ = _execute_packed(kw, vals, plan, length, n_keys=b * length,
+                            check=cfg.check)
+    with telemetry.span("sort.decode"):
+        return codec.decode(tuple(w[:b] for w in sk))
 
 
+@_entry_span
 def argsort_batched(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG):
     """Per-row stable argsort of (B, L): row i of the result is
     ``np.argsort(keys[i], kind="stable")`` (descending via cfg).
@@ -903,10 +980,13 @@ def argsort_batched(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG):
             jnp.arange(length, dtype=jnp.int32)[None, :], (b, length)
         )
     _, plan, kw, vals, b = _batched_entry(keys, cfg)
-    _, perm = _execute_packed(kw, vals, plan, length, check=cfg.check)
-    return perm[:b]
+    _, perm = _execute_packed(kw, vals, plan, length, n_keys=b * length,
+                              check=cfg.check)
+    with telemetry.span("sort.decode"):
+        return perm[:b]
 
 
+@_entry_span
 def sort_kv_batched(keys: jax.Array, values: jax.Array,
                     cfg: SortConfig = DEFAULT_CONFIG):
     """Per-row stable (keys, values) sort of (B, L) keys by keys.
@@ -925,13 +1005,16 @@ def sort_kv_batched(keys: jax.Array, values: jax.Array,
     if b == 0 or length <= 1:
         return keys, values
     codec, plan, kw, vals, b = _batched_entry(keys, cfg)
-    sk, perm = _execute_packed(kw, vals, plan, length, check=cfg.check)
-    sk, perm = tuple(w[:b] for w in sk), perm[:b]
-    idx = perm.reshape(perm.shape + (1,) * (values.ndim - 2))
-    sv = jnp.take_along_axis(values, idx, axis=1)
-    return codec.decode(sk), sv
+    sk, perm = _execute_packed(kw, vals, plan, length, n_keys=b * length,
+                               check=cfg.check)
+    with telemetry.span("sort.decode"):
+        sk, perm = tuple(w[:b] for w in sk), perm[:b]
+        idx = perm.reshape(perm.shape + (1,) * (values.ndim - 2))
+        sv = jnp.take_along_axis(values, idx, axis=1)
+        return codec.decode(sk), sv
 
 
+@_entry_span
 def sort_batched_with_stats(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG):
     """Batched sort + per-round stats over the WHOLE batch.
 
@@ -949,9 +1032,11 @@ def sort_batched_with_stats(keys: jax.Array, cfg: SortConfig = DEFAULT_CONFIG):
         return keys, perm, []
     codec, plan, kw, vals, b = _batched_entry(keys, cfg)
     sk, perm, stats = _execute_packed(
-        kw, vals, plan, length, with_stats=True, check=cfg.check
+        kw, vals, plan, length, n_keys=b * length, with_stats=True,
+        check=cfg.check
     )
-    return codec.decode(tuple(w[:b] for w in sk)), perm[:b], stats
+    with telemetry.span("sort.decode"):
+        return codec.decode(tuple(w[:b] for w in sk)), perm[:b], stats
 
 
 # ----------------------------------------------------------------------
@@ -1001,24 +1086,28 @@ def _segment_sorted_packed(x: jax.Array, segment_offsets, cfg: SortConfig):
     they sort last and the per-row capacity bound is untouched.
     """
     n = x.shape[0]
-    layout = _segment_layout(n, segment_offsets)
-    _, lens, w, valid, src, _, _ = layout
-    codec = codec_for(x.dtype, cfg.descending)
-    kw = codec.encode(x)
-    validj = jnp.asarray(valid)
-    srcj = jnp.asarray(src)
-    col = jnp.asarray(np.arange(max(w, 1)), jnp.int32)[None, :]
-    pkw = tuple(jnp.where(validj, u[srcj], _MAXU) for u in kw)
-    pv = jnp.where(validj, col, jnp.int32(w) + col)
-    s_orig = lens.size
-    plan = resolve_plan(
-        max(w, 1), x.dtype, cfg, rows=s_orig, pad_rows=True
-    )
-    pkw, pv = _pad_rows(pkw, pv, plan)
-    skw, sv = _execute_packed(pkw, pv, plan, 2 * max(w, 1), check=cfg.check)
+    with telemetry.span("sort.plan"):
+        layout = _segment_layout(n, segment_offsets)
+        _, lens, w, valid, src, _, _ = layout
+        s_orig = lens.size
+        codec = codec_for(x.dtype, cfg.descending)
+        plan = resolve_plan(
+            max(w, 1), x.dtype, cfg, rows=s_orig, pad_rows=True
+        )
+    with telemetry.span("sort.encode"):
+        kw = codec.encode(x)
+        validj = jnp.asarray(valid)
+        srcj = jnp.asarray(src)
+        col = jnp.asarray(np.arange(max(w, 1)), jnp.int32)[None, :]
+        pkw = tuple(jnp.where(validj, u[srcj], _MAXU) for u in kw)
+        pv = jnp.where(validj, col, jnp.int32(w) + col)
+        pkw, pv = _pad_rows(pkw, pv, plan)
+    skw, sv = _execute_packed(pkw, pv, plan, 2 * max(w, 1), n_keys=n,
+                              check=cfg.check)
     return codec, tuple(u[:s_orig] for u in skw), sv[:s_orig], layout
 
 
+@_entry_span
 def segment_sort(x: jax.Array, segment_offsets,
                  cfg: SortConfig = DEFAULT_CONFIG) -> jax.Array:
     """Sort each segment x[off[i]:off[i+1]] independently, in place.
@@ -1046,10 +1135,13 @@ def segment_sort(x: jax.Array, segment_offsets,
         _segment_layout(n, segment_offsets)  # still validate offsets
         return x
     codec, skw, _, layout = _segment_sorted_packed(x, segment_offsets, cfg)
-    unpack = jnp.asarray(layout[5])
-    return codec.decode(tuple(jnp.take(u.reshape(-1), unpack) for u in skw))
+    with telemetry.span("sort.decode"):
+        unpack = jnp.asarray(layout[5])
+        return codec.decode(
+            tuple(jnp.take(u.reshape(-1), unpack) for u in skw))
 
 
+@_entry_span
 def segment_argsort(x: jax.Array, segment_offsets,
                     cfg: SortConfig = DEFAULT_CONFIG) -> jax.Array:
     """Per-segment stable argsort with GLOBAL indices: out[off[i]:off[i+1]]
@@ -1063,6 +1155,7 @@ def segment_argsort(x: jax.Array, segment_offsets,
         _segment_layout(n, segment_offsets)
         return jnp.arange(0, dtype=jnp.int32)
     _, _, sv, layout = _segment_sorted_packed(x, segment_offsets, cfg)
-    off, _, _, _, _, unpack_src, seg_of_pos = layout
-    local = jnp.take(sv.reshape(-1), jnp.asarray(unpack_src))
-    return jnp.asarray(off[seg_of_pos].astype(np.int32)) + local
+    with telemetry.span("sort.decode"):
+        off, _, _, _, _, unpack_src, seg_of_pos = layout
+        local = jnp.take(sv.reshape(-1), jnp.asarray(unpack_src))
+        return jnp.asarray(off[seg_of_pos].astype(np.int32)) + local

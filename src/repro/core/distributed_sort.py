@@ -44,6 +44,13 @@ trace-once / zero-retrace discipline exactly as the single-device path
 does).  The per-phase plans inherit the strategy dispatch (DESIGN.md
 §8), so shards can radix- or merge-sort their local runs.
 
+NAMES IN A TRACE: the deal ``all_to_all``s run under the scope
+``sort.deal``, the sample ``all_gather``s under ``sort.sample_exchange``
+and the bucket ``all_to_all``s under ``sort.exchange``; the local sorts
+carry the executor's own ``sort.level<d>`` and step scopes.  On the
+host, ``make_sharded_sort``'s function opens ``sort.sharded_argsort``
+and one ``sort.launch`` per attempt.
+
 Keys dispatch on the ``core/key_codec`` codecs like the single-device
 pipeline: ``make_sharded_sort`` accepts any codec dtype (64-bit keys
 travel as two uint32 words per element through every collective; x64
@@ -61,7 +68,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import faults, guard
+from repro.core import faults, guard, telemetry
 from repro.core.bucket_sort import _run_node
 from repro.core.key_codec import codec_for
 from repro.core.plan import ShardPlan, SortPlan, build_shard_plan, shard_geometry
@@ -71,17 +78,14 @@ from repro.kernels.bitonic import as_words, like_words
 
 _MAXU = jnp.uint32(0xFFFFFFFF)
 
-# Python-side retrace counter for the jit'd distributed entry
-# (increments once per TRACE, not per call) — the distributed analogue
-# of ``bucket_sort.trace_count``; tests assert same-(mesh, n, dtype,
-# plan) => one trace and plan-cache hit => zero retraces with it.
-_TRACE_COUNT = 0
-
 
 def trace_count() -> int:
     """Number of times the distributed entry has been TRACED in this
-    process (a retrace/compile-discipline counter for tests)."""
-    return _TRACE_COUNT
+    process (the ``sort.sharded_traces`` counter) — the distributed
+    analogue of ``bucket_sort.trace_count``; tests assert same-(mesh,
+    n, dtype, plan) => one trace and plan-cache hit => zero retraces
+    with it."""
+    return telemetry.counts().get("sort.sharded_traces", 0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -207,8 +211,11 @@ def sorted_shard(keys_local, vals_local: jax.Array, plan: ShardPlan):
     pad_base += 4 * n_glob  # disjoint pad range headroom per phase
 
     # 2. deal: one static all_to_all transpose per word + payload
-    kw = tuple(_deal_all_to_all(w, ax, d, n_pad).reshape(n_pad) for w in kw)
-    v = _deal_all_to_all(v, ax, d, n_pad).reshape(n_pad)
+    with jax.named_scope("sort.deal"):
+        kw = tuple(
+            _deal_all_to_all(w, ax, d, n_pad).reshape(n_pad) for w in kw
+        )
+        v = _deal_all_to_all(v, ax, d, n_pad).reshape(n_pad)
 
     # 3. local sort of dealt data
     kw, v = _local_sort(kw, v, plan.dealt_plan, pad_base)
@@ -216,10 +223,12 @@ def sorted_shard(keys_local, vals_local: jax.Array, plan: ShardPlan):
 
     # 4. sampling -> replicated splitters (steps 3-5 of Algorithm 1)
     samp_idx = (jnp.arange(1, s_loc + 1, dtype=jnp.int32) * (n_pad // s_loc)) - 1
-    skw_all = tuple(
-        jax.lax.all_gather(w[samp_idx], ax).reshape(d * s_loc) for w in kw
-    )
-    sv_all = jax.lax.all_gather(v[samp_idx], ax).reshape(d * s_loc)
+    with jax.named_scope("sort.sample_exchange"):
+        skw_all = tuple(
+            jax.lax.all_gather(w[samp_idx], ax).reshape(d * s_loc)
+            for w in kw
+        )
+        sv_all = jax.lax.all_gather(v[samp_idx], ax).reshape(d * s_loc)
     sskw, ssv = _local_sort(skw_all, sv_all, plan.sample_plan, pad_base)
     pad_base += 4 * d * s_loc
     sp_idx = (jnp.arange(1, d, dtype=jnp.int32) * (d * s_loc)) // d
@@ -254,18 +263,22 @@ def sorted_shard(keys_local, vals_local: jax.Array, plan: ShardPlan):
     pad_base += d * d * c_pair
 
     faults.check("collective.exchange")  # trace-time chaos site (§11)
-    bkw = tuple(
-        jax.lax.all_to_all(
-            w.reshape(d, c_pair), ax, split_axis=0, concat_axis=0, tiled=False
+    with jax.named_scope("sort.exchange"):
+        bkw = tuple(
+            jax.lax.all_to_all(
+                w.reshape(d, c_pair), ax, split_axis=0, concat_axis=0,
+                tiled=False,
+            )
+            for w in bkw
         )
-        for w in bkw
-    )
-    bv = jax.lax.all_to_all(
-        bv.reshape(d, c_pair), ax, split_axis=0, concat_axis=0, tiled=False
-    )
-    recv_counts = jax.lax.all_to_all(
-        counts.reshape(d, 1), ax, split_axis=0, concat_axis=0, tiled=False
-    ).reshape(d)
+        bv = jax.lax.all_to_all(
+            bv.reshape(d, c_pair), ax, split_axis=0, concat_axis=0,
+            tiled=False,
+        )
+        recv_counts = jax.lax.all_to_all(
+            counts.reshape(d, 1), ax, split_axis=0, concat_axis=0,
+            tiled=False,
+        ).reshape(d)
 
     # 7. local sort of the received buckets (step 9); reals sort before pads
     fkw, fv = _local_sort(
@@ -294,8 +307,7 @@ def _sharded_argsort(keys, mesh, plan: ShardPlan):
     arguments: two ``make_sharded_sort`` calls with equal
     ``(shape, mesh, dtype, plan)`` signatures hit one compiled
     executable (trace-once / zero-retrace, tested)."""
-    global _TRACE_COUNT
-    _TRACE_COUNT += 1  # python side effect: runs once per TRACE
+    telemetry.count("sort.sharded_traces")  # python side effect: per TRACE
     codec = codec_for(plan.dtype_name, plan.descending)
     axt = plan.axis
     n_loc = plan.n_local
@@ -460,6 +472,10 @@ def make_sharded_sort(
     )
 
     def run(keys):
+        with telemetry.span("sort.sharded_argsort"):
+            return _run(keys)
+
+    def _run(keys):
         if jnp.dtype(keys.dtype).name != plan.dtype_name:
             raise ValueError(
                 f"keys dtype {jnp.dtype(keys.dtype).name} does not match "
@@ -472,14 +488,16 @@ def make_sharded_sort(
         # outcome is recorded on ``run.last_stats``.
         site = f"collective.exchange[D={plan.d}]"
         try:
-            out = _sharded_argsort(keys, mesh, plan)
+            with telemetry.span("sort.launch"):
+                out = _sharded_argsort(keys, mesh, plan)
             run.last_stats = {"degraded": False, "retries": 0}
             return out
         except guard.RECOVERABLE as e1:
             guard.record_degradation(
                 site, "retry", "mesh execution", "mesh execution (retry)", e1)
         try:
-            out = _sharded_argsort(keys, mesh, plan)
+            with telemetry.span("sort.launch"):
+                out = _sharded_argsort(keys, mesh, plan)
             run.last_stats = {"degraded": False, "retries": 1}
             return out
         except guard.RECOVERABLE as e2:

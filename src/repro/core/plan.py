@@ -141,6 +141,17 @@ class LevelPlan:
                    self.sample_plan.max_elements(),
                    self.bucket_plan.max_elements())
 
+    def moved_elements(self) -> int:
+        """Elements per array that relocation and compaction write in
+        this node and its children: the dense bucket array
+        (rows * s_round * cap) and the compacted rows (rows * lp) of
+        every bucket round, sample rounds included; 0 for direct."""
+        if self.kind != "bucket":
+            return 0
+        return (self.bucket_elements + self.elements
+                + self.sample_plan.moved_elements()
+                + self.bucket_plan.moved_elements())
+
     def pad_span(self) -> int:
         """Pad payloads this node draws above the ``pad_base`` it is
         entered with: its own column padding, the relocation pads of a
@@ -202,6 +213,13 @@ class SortPlan:
             n += 1
             node = node.bucket_plan
         return n
+
+    @functools.cached_property
+    def moved_elements(self) -> int:
+        """Elements per array that relocation and compaction write in
+        one call (:meth:`LevelPlan.moved_elements` of the root),
+        computed once per plan object: the entry counts it every call."""
+        return self.root.moved_elements()
 
     def signature(self) -> tuple:
         """The cache identity: (shape, dtype, backend, cfg-fingerprint)."""

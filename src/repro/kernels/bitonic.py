@@ -263,11 +263,13 @@ def _block_kernel(*refs, num_words: int, block_rows: int, sort_rows):
 
 
 def tile_sort_call(words, vals, num_samples: int, block_rows,
-                   interpret: bool, sort_rows=None):
+                   interpret: bool, sort_rows=None,
+                   name: str = "bitonic_tile_sort"):
     """Shared row-blocked pallas launch for every local-sort strategy:
     grid over (block_rows, T) blocks, then the optional Step-3 samples.
     ``sort_rows(words_tuple, vals) -> (words, vals)`` sorts each row of
-    the block; None selects the bitonic network.
+    the block; None selects the bitonic network.  ``name`` names the
+    kernel in compiled code and traces (after the strategy).
 
     Sample j of a sorted row is element (j+1)*T/s - 1, the last element
     of chunk j.  Mosaic refuses every in-kernel form of that lane-strided
@@ -294,6 +296,7 @@ def tile_sort_call(words, vals, num_samples: int, block_rows,
         + [jax.ShapeDtypeStruct((m, t), jnp.int32)],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name=name,
     )(*words, vals)
     if not num_samples:
         return out

@@ -153,6 +153,7 @@ def sort_tiles_kv(
     out = tile_sort_call(
         words, vals, 0, block_rows, interpret,
         sort_rows=functools.partial(merge_sort_rows, merge_run=merge_run),
+        name="merge_tile_sort",
     )
     return like_words(tuple(out[:-1]), keys), out[-1]
 
@@ -177,6 +178,7 @@ def sort_tiles_sample_kv(
     out = tile_sort_call(
         words, vals, num_samples, block_rows, interpret,
         sort_rows=functools.partial(merge_sort_rows, merge_run=merge_run),
+        name="merge_tile_sort",
     )
     return (
         like_words(tuple(out[:nw]), keys),

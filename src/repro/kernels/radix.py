@@ -224,6 +224,7 @@ def sort_tiles_kv(
     out = tile_sort_call(
         words, vals, 0, block_rows, interpret,
         sort_rows=functools.partial(radix_sort_rows, radix_bits=radix_bits),
+        name="radix_tile_sort",
     )
     return like_words(tuple(out[:-1]), keys), out[-1]
 
@@ -248,6 +249,7 @@ def sort_tiles_sample_kv(
     out = tile_sort_call(
         words, vals, num_samples, block_rows, interpret,
         sort_rows=functools.partial(radix_sort_rows, radix_bits=radix_bits),
+        name="radix_tile_sort",
     )
     return (
         like_words(tuple(out[:nw]), keys),

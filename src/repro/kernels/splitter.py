@@ -173,6 +173,7 @@ def splitter_partition(
         ],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="splitter_partition",
     )(*words, vals, *sp_words, sp_vals)
 
 

@@ -87,5 +87,6 @@ def topk_desc(
         + [jax.ShapeDtypeStruct((r, k), jnp.int32)],
         compiler_params=compiler_params(),
         interpret=interpret,
+        name="topk_desc",
     )(*words)
     return like_words(tuple(out[:nw]), keys), out[nw]

@@ -12,7 +12,9 @@ hold, so it happens inside a fixture and never at import.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +113,31 @@ def test_sort_planned_compiles(one_chip):
     x = jax.ShapeDtypeStruct((n,), I32, sharding=one_chip)
     compiled = _compile(lambda a: bucket_sort.sort_planned(a, plan), x)
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_sort_planned_gathers_carry_their_step_scope(one_chip):
+    """The whole-array gathers, and the fusions the compiler builds
+    round them, keep the executor's step scope in their op_name, so a
+    trace joined with the compiled text names relocation and
+    compaction."""
+    n = 1 << 14
+    plan = build_plan(n, jnp.int32, NATIVE)
+    x = jax.ShapeDtypeStruct((n,), I32, sharding=one_chip)
+    text = _compile(lambda a: bucket_sort.sort_planned(a, plan), x).as_text()
+    steps = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = [a-z0-9]+\[([\d,]*)\]\S* "
+                     r"(gather|fusion)\(.*op_name=\"([^\"]*)\"", line)
+        if m is None or not m.group(3).endswith("/gather"):
+            continue
+        if math.prod(int(d) for d in m.group(1).split(",") if d) < n:
+            continue
+        assert re.search(r"/sort\.level\d+/", m.group(3)), m.group(3)
+        step = re.findall(r"sort\.(relocate|compact)/", m.group(3))
+        assert step, m.group(3)
+        steps.append((m.group(2), step[-1]))
+    assert {s for _, s in steps} == {"relocate", "compact"}
+    assert {k for k, _ in steps} == {"gather", "fusion"}
 
 
 @pytest.mark.parametrize("strategy", ["radix", "merge"])
