@@ -16,7 +16,7 @@ Single-device deterministic sample sort.  The paper's nine steps map to
                                          slot, then one `take`
   step 9  sublist sort                -> recursion on bucket rows, then a
                                          gather-based compaction back to
-                                         dense rows
+                                         dense rows, by whole lane blocks
 
 NAMES IN A TRACE: every executor node opens ``jax.named_scope`` of its
 level (``sort.level0`` for the root, d + 1 for a sample or bucket
@@ -76,8 +76,11 @@ every codec dtype; 64-bit dtypes need x64 mode enabled.
 Relocation/compaction move the data by gather on the default path
 (DESIGN.md §4): both passes compute, for every destination slot, the
 source index it must read (per-chunk bases expanded over the slots by
-``_chunk_values``) and gather with `take`.  XLA serializes large 1-D
-scatters; gathers it vectorizes.  ``cfg.relocation="scatter"`` keeps the legacy
+``_chunk_values``) and gather with `take`.  Compaction's sources are
+whole sorted buckets, so it gathers one index per block of
+``compact_block`` lanes of a rotated bucket row instead
+(``_compact_blocked``).  XLA serializes large 1-D scatters; gathers it
+vectorizes.  ``cfg.relocation="scatter"`` keeps the legacy
 destination-scatter formulation as a reference path.
 
 Correctness invariants (tested, incl. hypothesis properties):
@@ -311,23 +314,88 @@ def _relocate_scatter(tkw, tv, ranks, starts, tile_off, r, m, s_round, t, cap,
     return bkw, bv.reshape(r * s_round, cap)
 
 
-def _compact_gather(ckw, cv, totals, r, s_round, cap, lp):
-    """Step 9 compaction, scatter-free: dense column c of data row r'
-    reads from bucket j covering c (:func:`_chunk_values` over the
-    s_round bucket offsets) at position c - bucket_off.  Bucket fills
-    sum to lp per row, so every dense slot has exactly one source — no
-    pads."""
+def _rotate_rows(x, shift, w: int):
+    """Rotate each row of ``x`` right by ``shift[q]`` (0 <= shift < w,
+    w a power of two), with the row held as (R, blocks, w).
+
+    A barrel shifter of log2(w) static lane rolls inside each block,
+    each kept where its bit of the shift is set, then one select that
+    takes lanes below the shift from the block before.  No gather, and
+    the (R, blocks, w) view is the layout of the (R*blocks, w) rows the
+    blocks are gathered from."""
+    for k in range(w.bit_length() - 1):
+        bit = (shift >> k) & 1
+        x = jnp.where(bit == 1, jnp.roll(x, 1 << k, axis=2), x)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 2)
+    return jnp.where(lane >= shift, x, jnp.roll(x, 1, axis=1))
+
+
+def _overlay(a, b):
+    """Scan operator of the boundary repair: each element is (first
+    lane, w-lane row); b's lanes from its first lane on cover a's."""
+    (sa, va), (sb, vb) = a, b
+    lane = jax.lax.broadcasted_iota(jnp.int32, vb.shape, vb.ndim - 1)
+    return jnp.minimum(sa, sb), jnp.where(lane >= sb, vb, va)
+
+
+def _compact_blocked(ckw, cv, totals, r, s_round, cap, lp, w):
+    """Step 9 compaction, scatter-free, by whole w-lane blocks
+    (DESIGN.md §4): dense row r' is the concatenation of each bucket's
+    first ``totals[r', j]`` elements.  Bucket fills sum to lp per row,
+    so every dense slot has exactly one source — no pads.
+
+    Bucket j of data row r' (bucket row q = r'*s_round + j) lands at
+    ``off = bucket_off[r', j]``.  Rotating bucket row q right by
+    ``off % w`` makes every output block that lies wholly inside the
+    bucket an aligned block of the rotated row: output block b is
+    rotated block ``b - off // w``.  The rest are boundary blocks, where
+    a bucket j >= 1 starts at a lane > 0: at most ``r * (s_round - 1)``
+    of them.  Candidate row k = r'*(s_round-1) + j-1 is the block where
+    bucket j starts, built from rotated blocks: the tail of bucket j-1,
+    then the first block of each bucket that starts inside it, laid
+    over one another in bucket order by a scan.  A boundary block reads
+    the candidate row of the last bucket that starts inside it.  The
+    candidate rows sit below the rotated rows, so one row gather with
+    one index per block writes the output.
+    """
     bucket_off = jnp.cumsum(totals, axis=1, dtype=jnp.int32) - totals  # (r, s_round)
-    # Column c of bucket j reads flat slot (row*s_round + j)*cap - off + c.
+    nb, cb, k = lp // w, cap // w, s_round - 1
     bucket = jax.lax.broadcasted_iota(jnp.int32, (r, s_round), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (r, s_round), 0)
-    base = (row * s_round + bucket) * cap - bucket_off
-    c = jax.lax.broadcasted_iota(jnp.int32, (r, lp), 1)
-    src = c + _chunk_values(bucket_off, base, lp)
-    srcf = src.reshape(-1)
-    okw = tuple(jnp.take(w.reshape(-1), srcf).reshape(r, lp) for w in ckw)
-    ov = jnp.take(cv.reshape(-1), srcf).reshape(r, lp)
-    return okw, ov
+    first_row = (row * s_round + bucket) * cb  # bucket's block 0, rotated
+    start, lane = bucket_off // w, bucket_off % w
+    # Block b from ceil(off / w) on reads rotated block row first_row + b - start.
+    b = jax.lax.broadcasted_iota(jnp.int32, (r, nb), 1)
+    src_row = b + _chunk_values(-(-bucket_off // w), first_row - start, nb)
+    # Boundary blocks: the last bucket j >= 1 that starts at a lane > 0.
+    last = jnp.zeros((r, nb), jnp.int32).at[row, start].max(
+        jnp.where(lane > 0, bucket, 0), mode="drop"
+    )
+    cand = jax.lax.broadcasted_iota(jnp.int32, (r, nb), 0) * k + last - 1
+    idx = jnp.where(last > 0, r * s_round * cb + cand, src_row).reshape(-1)
+    # Candidate j: bucket j-1's rotated block over block start_j (mod cb:
+    # a bucket that fills its cap wraps its last lanes to block 0), then
+    # bucket j's block 0 from lane_j on.  Where bucket j-1 also starts in
+    # block start_j, the scan's earlier rows supply the lanes below lane_j;
+    # a bucket starting at lane 0 leaves the scan as it was.
+    head = first_row[:, 1:]
+    tail = first_row[:, :-1] + (start[:, 1:] - start[:, :-1]) % cb
+    lane_j = lane[:, 1:, None]
+    opens = bucket_off[:, :-1, None] <= start[:, 1:, None] * w
+    from_lane = jnp.where(lane_j == 0, w, jnp.where(opens, 0, lane_j))
+    shift = lane.reshape(r * s_round, 1, 1)
+
+    def compact(x):
+        rot = _rotate_rows(x.reshape(r * s_round, cb, w), shift, w).reshape(
+            r * s_round * cb, w)
+        lanes = jax.lax.broadcasted_iota(jnp.int32, (r, k, w), 2)
+        pieces = jnp.where(lanes >= lane_j, rot[head], rot[tail])
+        _, cand_rows = jax.lax.associative_scan(
+            _overlay, (from_lane, pieces), axis=1)
+        rows = jnp.concatenate([rot, cand_rows.reshape(r * k, w)])
+        return jnp.take(rows, idx, axis=0).reshape(r, lp)
+
+    return tuple(compact(x) for x in ckw), compact(cv)
 
 
 def _compact_scatter(ckw, cv, totals, r, s_round, cap, lp):
@@ -497,7 +565,8 @@ def _bucket_round(kw, vals, node: LevelPlan, impl: str, interpret: bool,
     # the elements this level relocated there (fresh pads sort after them).
     with jax.named_scope("sort.compact"):
         if node.relocation == "gather":
-            okw, ov = _compact_gather(ckw, cv, totals, r, s_round, cap, lp)
+            okw, ov = _compact_blocked(ckw, cv, totals, r, s_round, cap, lp,
+                                       node.compact_block)
         else:
             okw, ov = _compact_scatter(ckw, cv, totals, r, s_round, cap, lp)
         return tuple(w[:, :length] for w in okw), ov[:, :length], pad_base

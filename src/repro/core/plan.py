@@ -45,6 +45,8 @@ from repro.core.sort_config import SortConfig, next_pow2, round_up
 # deep; hitting this means a degenerate config (e.g. s == tile with
 # length > direct_max, where the sample array never shrinks).
 _MAX_DEPTH = 64
+# Lanes per block of the blocked compaction: one TPU vreg row.
+COMPACT_BLOCK = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -81,6 +83,11 @@ class LevelPlan:
             executor dispatches ``ops.sort_tiles`` on it.
         radix_bits / merge_run: strategy knobs carried alongside
             (consulted only by the matching strategy).
+        compact_block: lanes W of the blocks step 9's gather compaction
+            moves whole (DESIGN.md §4): ``min(COMPACT_BLOCK, tile)``,
+            which divides ``lp`` (a multiple of the power-of-two tile)
+            and ``cap`` (a multiple of 128).  0 on the scatter path and
+            for direct nodes.
         sample_plan: step-4 recursion on the (rows, m*s) sample array.
         bucket_plan: step-9 recursion on the (rows*s_round, cap)
             bucket rows.
@@ -103,6 +110,7 @@ class LevelPlan:
     strategy: str = "bitonic"
     radix_bits: int = 4
     merge_run: int = 512
+    compact_block: int = 0
     sample_plan: "LevelPlan | None" = None
     bucket_plan: "LevelPlan | None" = None
 
@@ -255,7 +263,7 @@ class SortPlan:
                 f"tile={node.tile} s={node.s} m={node.m} "
                 f"s_round={node.s_round} cap={node.cap} "
                 f"block_rows={node.block_rows} reloc={node.relocation} "
-                f"strategy={node.strategy}"
+                f"compact_block={node.compact_block} strategy={node.strategy}"
             )
             node = node.bucket_plan
             depth += 1
@@ -345,6 +353,7 @@ def _build_node(
     s_round = min(max(next_pow2(-(-2 * lp // t)), 2), sper)
     # The paper's guaranteed capacity (DESIGN.md §2), lane-aligned.
     cap = round_up(lp // s_round + lp // sper, 128)
+    compact_block = min(COMPACT_BLOCK, t) if cfg.relocation == "gather" else 0
     part_block_rows = None
     if impl == "pallas" and cfg.fuse_ranking:
         from repro.kernels import splitter
@@ -370,6 +379,7 @@ def _build_node(
         strategy=cfg.strategy,
         radix_bits=cfg.radix_bits,
         merge_run=cfg.merge_run,
+        compact_block=compact_block,
         sample_plan=_build_node(rows, m * sper, cfg, impl, nw, depth + 1),
         bucket_plan=_build_node(
             rows * s_round, cap, cfg, impl, nw, depth + 1
@@ -923,10 +933,11 @@ def build_shard_plan(
 # ----------------------------------------------------------------------
 
 # v2: LevelPlan grew the per-level strategy fields (strategy /
-# radix_bits / merge_run).  Pre-strategy v1 records fail plan_from_dict
-# with a ValueError, which the autotune store treats as a clean cache
-# miss (re-tune and overwrite) — never a silently misread plan.
-_SCHEMA = "sort_plan/v2"
+# radix_bits / merge_run).  v3: LevelPlan grew ``compact_block``.
+# Older records fail plan_from_dict with a ValueError, which the
+# autotune store treats as a clean cache miss (re-tune and overwrite)
+# — never a silently misread plan.
+_SCHEMA = "sort_plan/v3"
 
 
 def _node_to_dict(node: LevelPlan | None):
@@ -981,7 +992,7 @@ def plan_json(plan: SortPlan) -> str:
 
 
 # v1: the initial distributed-schedule record.  The four per-phase
-# sub-plans are embedded as full sort_plan/v2 records, so a sort-plan
+# sub-plans are embedded as full sort_plan records, so a sort-plan
 # schema bump invalidates stored shard plans too (plan_from_dict raises
 # and the autotune store treats the record as a clean miss).
 _SHARD_SCHEMA = "shard_plan/v1"

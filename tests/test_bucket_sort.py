@@ -171,3 +171,112 @@ def test_int32_budget_admits_largest_single_chip_size():
     n = 1 << 27
     plan = bucket_sort.resolve_plan(n, jnp.int32, SortConfig(impl="xla"))
     bucket_sort.check_pad_budget(plan, n)
+
+
+# Built bucket fills for step 9's compaction: (rows, s_round, cap, lp,
+# fills per row, lanes w per block).  Block edges are multiples of w;
+# the plan's w is min(128, tile), so a tile below 128 gives narrower
+# blocks.
+COMPACT_FILLS = {
+    "empty_buckets": (2, 4, 384, 512, [[0, 256, 0, 256], [128, 0, 384, 0]],
+                      128),
+    "shorter_than_a_block": (
+        1, 8, 256, 256, [[50, 3, 77, 1, 60, 40, 20, 5]], 128),
+    "several_in_one_block": (
+        1, 8, 384, 384, [[10, 20, 30, 5, 7, 40, 16, 256]], 128),
+    "one_bucket_holds_the_row": (
+        2, 4, 512, 512, [[0, 512, 0, 0], [512, 0, 0, 0]], 128),
+    "full_cap_off_an_edge": (1, 4, 256, 512, [[100, 256, 156, 0]], 128),
+    "ends_on_block_edges": (1, 4, 256, 512, [[128, 256, 0, 128]], 128),
+    "ends_off_block_edges": (1, 4, 256, 512, [[129, 127, 255, 1]], 128),
+    "last_block_starts": (1, 4, 256, 384, [[256, 100, 27, 1]], 128),
+    "tile_64_rows": (
+        2, 4, 256, 320, [[0, 200, 120, 0], [5, 99, 100, 116]], 64),
+    "eight_lane_blocks": (
+        1, 8, 128, 256, [[3, 17, 0, 40, 8, 100, 64, 24]], 8),
+    "two_lane_blocks": (2, 4, 128, 130, [[1, 64, 0, 65], [0, 0, 127, 3]], 2),
+}
+
+
+def _bucket_rows(rng, rows, cap):
+    k = jnp.asarray(rng.integers(0, 2**32, (rows, cap), dtype=np.uint32))
+    v = jnp.asarray(rng.integers(0, 2**31 - 1, (rows, cap), dtype=np.int32))
+    return k, v
+
+
+@pytest.mark.parametrize("case", sorted(COMPACT_FILLS))
+def test_blocked_compaction_matches_gather_and_scatter(rng, case):
+    """Blocked compaction (DESIGN.md §4) gives the scatter reference's
+    dense rows bit for bit, and those of a NumPy gather of each
+    bucket's filled prefix."""
+    r, s_round, cap, lp, fills, w = COMPACT_FILLS[case]
+    totals = jnp.asarray(fills, jnp.int32)
+    assert (np.asarray(fills).sum(axis=1) == lp).all()
+    assert lp % w == 0 and cap % w == 0
+    k, v = _bucket_rows(rng, r * s_round, cap)
+    args = (totals, r, s_round, cap, lp)
+    got = bucket_sort._compact_blocked((k,), v, *args, w)
+    for x, out in ((k, got[0][0]), (v, got[1])):
+        rows = np.asarray(x).reshape(r, s_round, cap)
+        want = np.stack([
+            np.concatenate([rows[i, j, :f] for j, f in enumerate(fills[i])])
+            for i in range(r)])
+        np.testing.assert_array_equal(np.asarray(out), want)
+    ref = bucket_sort._compact_scatter((k,), v, *args)
+    np.testing.assert_array_equal(np.asarray(got[0][0]),
+                                  np.asarray(ref[0][0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(ref[1]))
+
+
+@pytest.mark.parametrize("tile", [16, 64])
+def test_small_tile_plans_compact_by_tile_wide_blocks(rng, tile):
+    """A tile below 128 plans blocks of ``tile`` lanes, which divide
+    every bucket node's padded length and capacity; the sort stays
+    stable through them."""
+    from repro.core.plan import build_plan
+
+    cfg = SortConfig(tile=tile, s=8, direct_max=128, impl="xla")
+    n = 1000
+    node, blocks = build_plan(n, jnp.int32, cfg).root, []
+    while node.kind == "bucket":
+        blocks.append(node.compact_block)
+        assert node.lp % tile == 0 and node.cap % tile == 0
+        node = node.bucket_plan
+    assert blocks and set(blocks) == {tile}
+    x = rng.integers(0, 9, n).astype(np.int32)
+    np.testing.assert_array_equal(
+        np.asarray(bucket_sort.argsort(jnp.asarray(x), cfg)),
+        np.argsort(x, kind="stable"))
+
+
+def _hbj_duplicates(n):
+    """Helman-Bader-JaJa deterministic duplicates: the first n/2 keys
+    are log2 n, the next n/4 are log2(n/2), and so on."""
+    out, start, size = np.empty(n, np.int32), 0, n // 2
+    while start < n:
+        size = max(size, 1)
+        out[start:start + size] = int(np.log2(n - start))
+        start += size
+        size //= 2
+    return out
+
+
+@pytest.mark.parametrize("dist", ["equal", "hbj_duplicates", "presorted"])
+def test_blocked_two_level_argsort_is_stable(rng, dist):
+    """Two bucket levels, both compacted by blocks, on the inputs that
+    make the most empty and the most lopsided buckets."""
+    from repro.core.plan import build_plan
+
+    cfg = SortConfig(tile=256, s=16, direct_max=256, impl="xla")
+    n = 5000
+    plan = build_plan(n, jnp.int32, cfg)
+    assert plan.num_levels == 2
+    assert plan.root.compact_block == plan.root.bucket_plan.compact_block == 128
+    if dist == "equal":
+        x = np.full(n, 7, np.int32)
+    elif dist == "hbj_duplicates":
+        x = _hbj_duplicates(n)
+    else:
+        x = np.sort(rng.integers(-1000, 1000, n).astype(np.int32))
+    perm = np.asarray(bucket_sort.argsort(jnp.asarray(x), cfg))
+    np.testing.assert_array_equal(perm, np.argsort(x, kind="stable"))

@@ -179,7 +179,7 @@ def test_strategy_stale_v1_cache_record_retunes_cleanly(tmp_path):
     )
     assert plan.root.strategy in STRATEGIES
     fresh = json.loads(path.read_text())["plans"][cache_key(base)]
-    assert fresh["plan"]["schema"] == "sort_plan/v2"
+    assert fresh["plan"]["schema"] == "sort_plan/v3"
 
 
 @pytest.mark.parametrize("strategy", ["radix", "merge"])

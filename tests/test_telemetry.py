@@ -121,6 +121,24 @@ def test_call_counters_advance_by_the_plan():
     assert plan.moved_elements == 16 * 512 + N + 64 * 256 + 16 * 512
 
 
+@pytest.mark.parametrize("n,blocked", [
+    # the 2^17 sample round of the 2^23 plan is compacted by blocks too
+    (1 << 17, 1 << 17), (1 << 23, (1 << 24) + (1 << 23) + (1 << 17))])
+def test_default_plan_compact_blocked(n, blocked):
+    """Every bucket round of a default plan compacts by 128-lane blocks:
+    ``blocked`` elements per array a call, the compaction's share of
+    ``sort.moved_elements``."""
+    plan = build_plan(n, jnp.int32, SortConfig())
+    nodes, todo = [], [plan.root]
+    while todo:
+        node = todo.pop()
+        if node is not None and node.kind == "bucket":
+            nodes.append(node)
+            todo += [node.sample_plan, node.bucket_plan]
+    assert {node.compact_block for node in nodes} == {128}
+    assert sum(node.elements for node in nodes) == blocked
+
+
 def test_keys_counter_leaves_out_segment_padding():
     offsets = [0, 100, 1000, 1003, 2500]  # rows pad to the longest, 1500
     x = _keys(offsets[-1])

@@ -140,6 +140,82 @@ def test_sort_planned_gathers_carry_their_step_scope(one_chip):
     assert {k for k, _ in steps} == {"gather", "fusion"}
 
 
+def _bucket_nodes(node):
+    if node is None or node.kind != "bucket":
+        return []
+    return ([node] + _bucket_nodes(node.sample_plan)
+            + _bucket_nodes(node.bucket_plan))
+
+
+@pytest.fixture(scope="module")
+def default_8m(one_chip):
+    """The default 2^23 program, as a benchmark call runs it: its plan
+    and its compiled text."""
+    n = 1 << 23
+    plan = build_plan(n, jnp.int32, NATIVE)
+    word = jax.ShapeDtypeStruct((1, n), U32, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((1, n), I32, sharding=one_chip)
+    text = bucket_sort._sort_canonical_packed.lower(
+        (word,), vals, plan=plan, pad_base0=n).compile().as_text()
+    return plan, text
+
+
+def _gathers(text, step):
+    """(output elements, slice sizes, data operand's shape and layout)
+    of every gather under the step's scope.  A gather inside a fusion
+    reads a parameter of the fused computation."""
+    params, found = {}, []
+    for line in text.splitlines():
+        m = re.match(r"\s*%(\S+) = (\S+) parameter\(", line)
+        if m:
+            params[m.group(1)] = m.group(2)
+        m = re.match(r"\s*(?:ROOT )?%\S+ = [a-z0-9]+\[([\d,]*)\]\S* "
+                     r"gather\(%([\w.\-]+), .*slice_sizes=\{([\d,]*)\}"
+                     r".*op_name=\"([^\"]*)\"", line)
+        if m and f"sort.{step}/" in m.group(4):
+            size = math.prod(int(d) for d in m.group(1).split(",") if d)
+            found.append((size, m.group(3), params.get(m.group(2), "")))
+    return found
+
+
+def test_default_compaction_gathers_whole_blocks(default_8m):
+    """Compaction moves its 2^24, 2^23 and 2^17 outputs as 128-lane
+    rows; every other gather under its scope (the boundary blocks'
+    pieces) has at most rows * (s_round - 1) * 128 outputs."""
+    plan, text = default_8m
+    nodes = _bucket_nodes(plan.root)
+    assert len(nodes) == 3
+    assert all(node.compact_block == 128 for node in nodes)
+    repair_max = max(node.rows * (node.s_round - 1) * 128 for node in nodes)
+    gathers = _gathers(text, "compact")
+    rows = [size for size, sl, _ in gathers if sl.split(",")[-1] == "128"]
+    for node in nodes:  # the key word and the payload
+        assert rows.count(node.rows * node.lp) == 2, (node.lp, rows)
+    outputs = {node.rows * node.lp for node in nodes}
+    rest = [size for size, _, _ in gathers if size not in outputs]
+    assert rest and max(rest) <= repair_max < 1 << 23
+
+
+def test_default_relocation_gathers_read_vmem(default_8m):
+    """The relocation gathers read their sources from VMEM (``S(1)``):
+    from HBM they ran 1.65x slower on a v5e, which a change to the rest
+    of the program can cause by moving the compiler's VMEM placement.
+
+    The placement is the installed TPU compiler's choice, so a failure
+    here says that the sort got slower, not that it is wrong: measure
+    relocation on the chip (``tools/step_times.py``) before accepting
+    the change, or a new compiler version, that moved it."""
+    _, text = default_8m
+    big = [(size, src) for size, _, src in _gathers(text, "relocate")
+           if size >= 1 << 23]
+    assert len(big) == 4, big  # two levels, key word and payload
+    assert all("S(1)" in src for _, src in big), (
+        "a relocation gather reads its source from HBM in this compile: "
+        "not a wrong result, but likely ~1.65x slower relocation on a "
+        "v5e; measure sort.relocate on the chip with tools/step_times.py",
+        big)
+
+
 @pytest.mark.parametrize("strategy", ["radix", "merge"])
 def test_strategy_without_native_kernel_raises(strategy):
     """radix and merge gather inside the kernel, which Mosaic cannot
