@@ -44,12 +44,18 @@ trace-once / zero-retrace discipline exactly as the single-device path
 does).  The per-phase plans inherit the strategy dispatch (DESIGN.md
 §8), so shards can radix- or merge-sort their local runs.
 
-NAMES IN A TRACE: the deal ``all_to_all``s run under the scope
-``sort.deal``, the sample ``all_gather``s under ``sort.sample_exchange``
-and the bucket ``all_to_all``s under ``sort.exchange``; the local sorts
-carry the executor's own ``sort.level<d>`` and step scopes.  On the
-host, ``make_sharded_sort``'s function opens ``sort.sharded_argsort``
-and one ``sort.launch`` per attempt.
+NAMES IN A TRACE: the four local sorts run under ``sort.phase_run``,
+``sort.phase_dealt``, ``sort.phase_sample`` and ``sort.phase_bucket``,
+each holding the executor's own ``sort.level<d>`` and step scopes; the
+deal ``all_to_all``s run under ``sort.deal``, the sample
+``all_gather``s under ``sort.sample_exchange``, the splitter ranks and
+chunk geometry under ``sort.partition``, the scatter into the
+``(D, C_pair)`` buffer under ``sort.pack`` and the bucket
+``all_to_all``s under ``sort.exchange``.  On the host,
+``make_sharded_sort``'s function opens ``sort.sharded_argsort`` and one
+``sort.launch`` per attempt, and counts ``sort.keys`` (n_global) and
+``sort.exchange_slots`` (``d * d * c_pair``: the slots per array that
+the bucket exchange carries, padding included) once per call.
 
 Keys dispatch on the ``core/key_codec`` codecs like the single-device
 pipeline: ``make_sharded_sort`` accepts any codec dtype (64-bit keys
@@ -207,7 +213,8 @@ def sorted_shard(keys_local, vals_local: jax.Array, plan: ShardPlan):
     pad_base += d * n_pad
 
     # 1. local sort
-    kw, v = _local_sort(kw, v, plan.run_plan, pad_base)
+    with jax.named_scope("sort.phase_run"):
+        kw, v = _local_sort(kw, v, plan.run_plan, pad_base)
     pad_base += 4 * n_glob  # disjoint pad range headroom per phase
 
     # 2. deal: one static all_to_all transpose per word + payload
@@ -218,7 +225,8 @@ def sorted_shard(keys_local, vals_local: jax.Array, plan: ShardPlan):
         v = _deal_all_to_all(v, ax, d, n_pad).reshape(n_pad)
 
     # 3. local sort of dealt data
-    kw, v = _local_sort(kw, v, plan.dealt_plan, pad_base)
+    with jax.named_scope("sort.phase_dealt"):
+        kw, v = _local_sort(kw, v, plan.dealt_plan, pad_base)
     pad_base += 4 * n_glob
 
     # 4. sampling -> replicated splitters (steps 3-5 of Algorithm 1)
@@ -229,37 +237,41 @@ def sorted_shard(keys_local, vals_local: jax.Array, plan: ShardPlan):
             for w in kw
         )
         sv_all = jax.lax.all_gather(v[samp_idx], ax).reshape(d * s_loc)
-    sskw, ssv = _local_sort(skw_all, sv_all, plan.sample_plan, pad_base)
+    with jax.named_scope("sort.phase_sample"):
+        sskw, ssv = _local_sort(skw_all, sv_all, plan.sample_plan, pad_base)
+        sp_idx = (jnp.arange(1, d, dtype=jnp.int32) * (d * s_loc)) // d
+        spkw = tuple(w[sp_idx] for w in sskw)  # (D-1,) same on every device
+        spv = ssv[sp_idx]
     pad_base += 4 * d * s_loc
-    sp_idx = (jnp.arange(1, d, dtype=jnp.int32) * (d * s_loc)) // d
-    spkw = tuple(w[sp_idx] for w in sskw)  # (D-1,) identical on every device
-    spv = ssv[sp_idx]
 
     # 5. splitter ranks -> chunk geometry (steps 6-7)
-    ranks = ops.splitter_ranks(
-        tuple(w[None, :] for w in kw), v[None, :],
-        tuple(w[None, :] for w in spkw), spv[None, :],
-        impl=plan.impl, interpret=plan.interpret,
-    )[0]  # (D-1,) in [0, n_pad]
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ranks])
-    ends = jnp.concatenate([ranks, jnp.full((1,), n_pad, jnp.int32)])
-    counts = ends - starts  # (D,) elements per target device
+    with jax.named_scope("sort.partition"):
+        ranks = ops.splitter_ranks(
+            tuple(w[None, :] for w in kw), v[None, :],
+            tuple(w[None, :] for w in spkw), spv[None, :],
+            impl=plan.impl, interpret=plan.interpret,
+        )[0]  # (D-1,) in [0, n_pad]
+        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ranks])
+        ends = jnp.concatenate([ranks, jnp.full((1,), n_pad, jnp.int32)])
+        counts = ends - starts  # (D,) elements per target device
 
     # 6. scatter into the padded (D, C_pair) buffer, one static all_to_all
-    pos = jnp.arange(n_pad, dtype=jnp.int32)
-    ind = jnp.zeros((n_pad + 1,), jnp.int32).at[ranks].add(1)
-    chunk_id = jnp.cumsum(ind, dtype=jnp.int32)[:n_pad]
-    within = pos - jnp.take(starts, chunk_id)
-    max_within = jnp.max(within)  # bound check: < C_pair (tested)
-    dest = chunk_id * c_pair + within
-    dest = jnp.where(within < c_pair, dest, d * c_pair)
-    bkw = tuple(
-        jnp.full((d * c_pair,), _MAXU, jnp.uint32).at[dest].set(w, mode="drop")
-        for w in kw
-    )
-    bv = (
-        jnp.int32(pad_base) + jnp.arange(d * c_pair, dtype=jnp.int32)
-    ).at[dest].set(v, mode="drop")
+    with jax.named_scope("sort.pack"):
+        pos = jnp.arange(n_pad, dtype=jnp.int32)
+        ind = jnp.zeros((n_pad + 1,), jnp.int32).at[ranks].add(1)
+        chunk_id = jnp.cumsum(ind, dtype=jnp.int32)[:n_pad]
+        within = pos - jnp.take(starts, chunk_id)
+        max_within = jnp.max(within)  # bound check: < C_pair (tested)
+        dest = chunk_id * c_pair + within
+        dest = jnp.where(within < c_pair, dest, d * c_pair)
+        bkw = tuple(
+            jnp.full((d * c_pair,), _MAXU, jnp.uint32)
+            .at[dest].set(w, mode="drop")
+            for w in kw
+        )
+        bv = (
+            jnp.int32(pad_base) + jnp.arange(d * c_pair, dtype=jnp.int32)
+        ).at[dest].set(v, mode="drop")
     pad_base += d * d * c_pair
 
     faults.check("collective.exchange")  # trace-time chaos site (§11)
@@ -281,10 +293,11 @@ def sorted_shard(keys_local, vals_local: jax.Array, plan: ShardPlan):
         ).reshape(d)
 
     # 7. local sort of the received buckets (step 9); reals sort before pads
-    fkw, fv = _local_sort(
-        tuple(w.reshape(d * c_pair) for w in bkw), bv.reshape(d * c_pair),
-        plan.bucket_plan, pad_base,
-    )
+    with jax.named_scope("sort.phase_bucket"):
+        fkw, fv = _local_sort(
+            tuple(w.reshape(d * c_pair) for w in bkw), bv.reshape(d * c_pair),
+            plan.bucket_plan, pad_base,
+        )
     out_cap = plan.out_cap
     count = jnp.sum(recv_counts, dtype=jnp.int32)
     # Padded shard elements (payload in [n_glob, n_glob + d*n_pad)) are real
@@ -482,6 +495,8 @@ def make_sharded_sort(
                 f"the shard plan's dtype {plan.dtype_name} (pass dtype= to "
                 "make_sharded_sort)"
             )
+        telemetry.count("sort.keys", n_global)
+        telemetry.count("sort.exchange_slots", plan.d * plan.exchange_elements)
         # Degradation chain (DESIGN.md §11): mesh execution -> ONE retry
         # (a failed trace is never cached, so the retry re-traces from
         # scratch) -> deterministic gather-to-host degraded sort.  The

@@ -5,15 +5,19 @@ their exporter: they land on the host plane, on the device trace's
 clock, and a span opened while no profiler runs is a no-op TraceMe.
 Counters are plain in-process integers; :func:`counts` is their
 snapshot.  The device-side counterpart is ``jax.named_scope`` in the
-executor (``core/bucket_sort.py``), which names the compiled ops.
+executors (``core/bucket_sort.py``, ``core/distributed_sort.py``),
+which names the compiled ops.
 
 Names (PERF.md lists each with the metric that reads it):
 
-  spans     sort.<entry> (sort.argsort, sort.sort, ...), and inside it
-            sort.plan, sort.encode, sort.launch (one per attempt),
-            sort.decode
-  counters  sort.keys (real keys, padding left out) and
-            sort.moved_elements (once per call, at the launch);
+  spans     sort.<entry> (sort.argsort, sort.sort, ...,
+            sort.sharded_argsort), and inside it sort.plan,
+            sort.encode, sort.launch (one per attempt), sort.decode
+  counters  sort.keys (real keys, padding left out; n_global on the
+            mesh), sort.moved_elements (one device) and
+            sort.exchange_slots (the mesh: d * d * c_pair, the slots
+            per array the bucket all_to_all carries, padding
+            included), each once per call, at the launch;
             sort.traces, sort.sharded_traces (once per trace of the
             jitted executors)
 

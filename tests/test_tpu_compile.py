@@ -5,7 +5,8 @@ compiles for a topology that is only described, and refuses what Mosaic
 cannot lower or what does not fit VMEM.  Interpret mode accepts both, so
 these compiles are the only tier-1 guard on the native kernels.  Every
 compile runs at the widths ``chip_smoke.py`` uses (T=4096, s=64) on one
-described device.  This is the only test file that describes a topology:
+described device, and the 4-chip benchmark cell's sharded sort on all
+four.  This is the only test file that describes a topology:
 the description loads the TPU library, which one process at a time may
 hold, so it happens inside a fixture and never at import.
 """
@@ -18,10 +19,12 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
-from repro.core import autotune, bucket_sort
+from repro.core import autotune, bucket_sort, distributed_sort
 from repro.core.plan import build_plan
 from repro.core.sort_config import SortConfig
 from repro.kernels import bitonic, splitter, topk
@@ -214,6 +217,50 @@ def test_default_relocation_gathers_read_vmem(default_8m):
         "not a wrong result, but likely ~1.65x slower relocation on a "
         "v5e; measure sort.relocate on the chip with tools/step_times.py",
         big)
+
+
+@pytest.fixture(scope="module")
+def mesh_25m(topo, one_chip):
+    """The 4-chip benchmark cell's program (``bench/configs/
+    sharded_argsort_i32_4chip.json``): ``make_sharded_sort`` at 2^25
+    int32 keys over the four described chips, its plan and its compiled
+    program (``one_chip`` turns the compile cache off)."""
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    n = 1 << 25
+    _, plan = distributed_sort.make_sharded_sort(mesh, "data", n, NATIVE)
+    x = jax.ShapeDtypeStruct((n,), I32,
+                             sharding=NamedSharding(mesh, P("data")))
+    compiled = distributed_sort._sharded_argsort.lower(x, mesh, plan).compile()
+    return plan, compiled
+
+
+def test_mesh_phase_plans_are_native(mesh_25m):
+    plan, _ = mesh_25m
+    phases = [plan.run_plan, plan.dealt_plan, plan.sample_plan,
+              plan.bucket_plan]
+    assert all(p.impl == "pallas" and p.interpret is False for p in phases)
+    assert [p.num_levels for p in phases] == [2, 2, 0, 3]
+
+
+def test_mesh_collectives(mesh_25m):
+    """Five all_to_alls (the deal's key word and payload, the bucket
+    exchange's two arrays and its counts) and one collective per array
+    for the sample gather, which the compiler may emit as an
+    all-reduce."""
+    _, compiled = mesh_25m
+    ops = re.findall(r"= \S+ (all-to-all|all-gather|all-reduce|"
+                     r"collective-permute|reduce-scatter)(?:-start)?\(",
+                     compiled.as_text())
+    assert ops.count("all-to-all") == 5, ops
+    assert len(ops) == 7, ops
+
+
+def test_mesh_fits_each_chip(mesh_25m):
+    _, compiled = mesh_25m
+    m = compiled.memory_analysis()
+    per_chip = (m.argument_size_in_bytes + m.output_size_in_bytes
+                + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert per_chip < 15.75 * 2**30, per_chip
 
 
 @pytest.mark.parametrize("strategy", ["radix", "merge"])
