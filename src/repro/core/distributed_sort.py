@@ -55,7 +55,10 @@ chunk geometry under ``sort.partition``, the scatter into the
 ``make_sharded_sort``'s function opens ``sort.sharded_argsort`` and one
 ``sort.launch`` per attempt, and counts ``sort.keys`` (n_global) and
 ``sort.exchange_slots`` (``d * d * c_pair``: the slots per array that
-the bucket exchange carries, padding included) once per call.
+the bucket exchange carries, padding included) and
+``sort.mesh_moved_elements`` (``d`` times the elements per array that
+relocation and compaction write on one device, over the four phase
+plans) once per call.
 
 Keys dispatch on the ``core/key_codec`` codecs like the single-device
 pipeline: ``make_sharded_sort`` accepts any codec dtype (64-bit keys
@@ -497,6 +500,8 @@ def make_sharded_sort(
             )
         telemetry.count("sort.keys", n_global)
         telemetry.count("sort.exchange_slots", plan.d * plan.exchange_elements)
+        telemetry.count("sort.mesh_moved_elements",
+                        plan.d * plan.moved_elements)
         # Degradation chain (DESIGN.md §11): mesh execution -> ONE retry
         # (a failed trace is never cached, so the retry re-traces from
         # scratch) -> deterministic gather-to-host degraded sort.  The

@@ -780,6 +780,16 @@ class ShardPlan:
         elements sent and received in the fixed-shape all_to_all)."""
         return self.d * self.c_pair
 
+    @functools.cached_property
+    def moved_elements(self) -> int:
+        """Elements per array that relocation and compaction write on
+        one device in one call: the sum of the four phase plans'
+        :attr:`SortPlan.moved_elements` (every device runs the same
+        four plans)."""
+        return sum(p.moved_elements for p in (
+            self.run_plan, self.dealt_plan, self.sample_plan,
+            self.bucket_plan))
+
     @property
     def collective_elements(self) -> int:
         """Total per-device interconnect elements across the schedule:

@@ -39,7 +39,15 @@ class SortConfig:
         Power of two; multiple of 128 for lane alignment on real TPU.
     s: samples per tile == max buckets per round (paper: s = 64, Fig. 3).
     direct_max: arrays up to this length are bitonic-sorted directly in a
-        single tile instead of going through a bucket round.
+        single tile instead of going through a bucket round.  16384 by
+        default: on a v5e a direct sort costs ~7.6 ps an element a
+        network pass (4096 x 8192, 91 passes, in 23.2 ms), while a bucket
+        round's relocation gathers cost 8.6 ns an element a word from
+        VMEM and ~22 ns from HBM, so one direct sort of a row of up to
+        16384 beats any bucket round over it.  This makes the 4-chip
+        sort's 9344-long level-1 buckets (``c_pair``'s slack puts them
+        just past 8192) go direct instead of through a third level.  Not
+        higher: the unrolled network's code grows with T * log2(T)^2.
     impl: "pallas" (kernels) | "xla" (pure-jnp reference path) | None=auto.
     interpret: Pallas interpret mode (None = auto: True off-TPU).
     block_rows: tiles sorted per grid program in the row-blocked bitonic
@@ -118,7 +126,7 @@ class SortConfig:
 
     tile: int = 4096
     s: int = 64
-    direct_max: int = 8192
+    direct_max: int = 16384
     impl: str | None = None
     interpret: bool | None = None
     block_rows: int | None = None

@@ -17,7 +17,9 @@ Names (PERF.md lists each with the metric that reads it):
             mesh), sort.moved_elements (one device) and
             sort.exchange_slots (the mesh: d * d * c_pair, the slots
             per array the bucket all_to_all carries, padding
-            included), each once per call, at the launch;
+            included), sort.mesh_moved_elements (the mesh: d times one
+            device's moved elements over the four phase plans), each
+            once per call, at the launch;
             sort.traces, sort.sharded_traces (once per trace of the
             jitted executors)
 

@@ -212,6 +212,65 @@ def test_topk_plan_matches_lax_topk(rng):
 
 
 # ----------------------------------------------------------------------
+# The default direct-versus-bucket threshold (SortConfig.direct_max)
+# ----------------------------------------------------------------------
+
+_DEFAULT_PAL = SortConfig(impl="pallas")
+
+
+def _spine(node):
+    """(kind, rows, length, lp) of each node on the bucket_plan spine."""
+    out = []
+    while node is not None:
+        out.append((node.kind, node.rows, node.length, node.lp))
+        node = node.bucket_plan if node.kind == "bucket" else None
+    return out
+
+
+@pytest.mark.parametrize("n,levels,ratio,spine", [
+    (1 << 17, 1, 3.0, [("bucket", 1, 1 << 17, 1 << 17),
+                       ("direct", 64, 4096, 4096)]),
+    (1 << 23, 2, 9.046875, [("bucket", 1, 1 << 23, 1 << 23),
+                            ("bucket", 64, 1 << 18, 1 << 18),
+                            ("direct", 4096, 8192, 8192)]),
+])
+def test_default_plans_keep_their_shape(n, levels, ratio, spine):
+    """The benchmark's one-chip sizes have every node on the same side
+    of the 8192 and the 16384 threshold: raising ``direct_max`` leaves
+    their plans as they were, node for node."""
+    plan = build_plan(n, jnp.int32, _DEFAULT_PAL)
+    assert plan.num_levels == levels
+    assert plan.moved_elements == ratio * n
+    assert _spine(plan.root) == spine
+    old = build_plan(n, jnp.int32,
+                     dataclasses.replace(_DEFAULT_PAL, direct_max=8192))
+    assert plan.root == old.root
+
+
+@pytest.mark.parametrize("n,kind", [
+    (8193, "direct"), (16384, "direct"), (16385, "bucket")])
+def test_default_direct_threshold(n, kind):
+    root = build_plan(n, jnp.int32, _DEFAULT_PAL).root
+    assert SortConfig().direct_max == 16384
+    assert root.kind == kind
+    if kind == "direct":
+        assert root.lp == 16384
+
+
+@pytest.mark.parametrize("keys", ["uniform", "all_equal"])
+def test_default_direct_sort_past_8192_is_stable(keys, rng):
+    """A row of 12000 keys, past the old 8192 threshold, now takes the
+    default config's one direct 16384-lane kernel (interpret mode)."""
+    n = 12000
+    assert build_plan(n, jnp.int32, _DEFAULT_PAL).root.kind == "direct"
+    x = (rng.integers(-(2**31), 2**31, n, dtype=np.int64)
+         if keys == "uniform" else np.full(n, 7)).astype(np.int32)
+    got = bucket_sort.argsort(jnp.asarray(x), _DEFAULT_PAL)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.argsort(x, kind="stable"))
+
+
+# ----------------------------------------------------------------------
 # Compile-count discipline: same signature traces once; plan-cache hits
 # trace zero times
 # ----------------------------------------------------------------------

@@ -146,6 +146,24 @@ def test_build_shard_plan_deterministic_and_memoized():
     assert radix != a and radix.signature() != a.signature()
 
 
+def test_default_mesh_bucket_phase_goes_direct_at_level_one():
+    """The 4-chip benchmark cell's plan (2^23 keys a chip, default
+    config): ``c_pair``'s slack makes the bucket phase's level-1
+    buckets 9344 long, which the default ``direct_max`` (16384) sorts
+    directly, so that phase has two bucket levels, not three."""
+    plan = build_shard_plan("data", 4, 1 << 23, "int32",
+                            SortConfig(impl="pallas"))
+    phases = (plan.run_plan, plan.dealt_plan, plan.sample_plan,
+              plan.bucket_plan)
+    assert [p.num_levels for p in phases] == [2, 2, 0, 2]
+    child = plan.bucket_plan.root.bucket_plan.bucket_plan
+    assert child.kind == "direct"
+    assert (child.rows, child.length, child.lp, child.block_rows) == (
+        4096, 9344, 16384, 32)
+    assert plan.moved_elements == sum(p.moved_elements for p in phases)
+    assert plan.d * plan.moved_elements / (1 << 25) == 28.3681640625
+
+
 def test_shard_plan_signature_separates_schedules():
     base = build_shard_plan("data", 4, 2048, "int32", _XLA)
     for other in (

@@ -5,10 +5,10 @@ plain reference ``np.argsort(kind="stable")``.
 The configuration's own settings (D = 4, oversample 8, pair_align 8,
 int32 keys, the global index as payload, Pallas kernels) at a tile and
 sample count cut so that the phases have the chip plan's shape at 2^25
-keys: two-level run and dealt sorts, and a three-level bucket sort at a
-length that is not a power of two.  One child process runs every
-check, since the main process keeps the real one-CPU topology; each
-test reads its part of the child's report.
+keys: two-level run and dealt sorts, and a two-level bucket sort at a
+length that is not a power of two, whose level-1 buckets go direct.
+One child process runs every check, since the main process keeps the
+real one-CPU topology; each test reads its part of the child's report.
 """
 
 from __future__ import annotations
@@ -36,7 +36,7 @@ CHILD = """
 
     mesh = jax.make_mesh((4,), ("data",))
     n = 8192
-    cfg = SortConfig(impl="pallas", tile=128, s=8, direct_max=128)
+    cfg = SortConfig(impl="pallas", tile=128, s=8, direct_max=256)
     run, plan = make_sharded_sort(mesh, "data", n, cfg, 8,
                                   dtype=jnp.int32, pair_align=8)
     rng = np.random.default_rng(2**31 + 15)
@@ -97,7 +97,7 @@ def report():
 
 def test_phases_have_the_chip_plans_shape(report):
     plan = report["plan"]
-    assert plan["levels"] == [2, 2, 0, 3]
+    assert plan["levels"] == [2, 2, 0, 2]
     length = plan["bucket_length"]
     assert length == plan["d"] * plan["c_pair"]
     assert length & (length - 1) != 0

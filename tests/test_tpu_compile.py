@@ -76,6 +76,17 @@ def test_tile_sort_compiles(one_chip, num_words):
         tuple(w), v, interpret=False), words, vals)
 
 
+def test_widest_direct_sort_compiles(one_chip):
+    """The default config's widest direct sort (``SortConfig.direct_max``
+    = 16384 lanes), which the 4-chip cell's bucket phase runs on its
+    9344-long level-1 buckets, at its auto row block (32 rows)."""
+    w = jax.ShapeDtypeStruct((64, 16384), U32, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((64, 16384), I32, sharding=one_chip)
+    assert bitonic.effective_block_rows(64, 16384, None) == 32
+    _compile(lambda w, v: bitonic.sort_tiles_kv(
+        (w,), v, interpret=False), w, v)
+
+
 def test_tile_sort_with_samples_compiles(one_chip):
     k = jax.ShapeDtypeStruct((64, T), U32, sharding=one_chip)
     v = jax.ShapeDtypeStruct((64, T), I32, sharding=one_chip)
@@ -109,7 +120,7 @@ def test_topk_compiles(one_chip):
 def test_sort_planned_compiles(one_chip):
     """One whole program: a bucket round with fused sampling and
     partition, a direct sample sort, and the bucket sort."""
-    n = 1 << 14
+    n = 1 << 15
     plan = build_plan(n, jnp.int32, NATIVE)
     assert plan.impl == "pallas" and plan.interpret is False
     assert plan.root.kind == "bucket"
@@ -123,7 +134,7 @@ def test_sort_planned_gathers_carry_their_step_scope(one_chip):
     round them, keep the executor's step scope in their op_name, so a
     trace joined with the compiled text names relocation and
     compaction."""
-    n = 1 << 14
+    n = 1 << 15
     plan = build_plan(n, jnp.int32, NATIVE)
     x = jax.ShapeDtypeStruct((n,), I32, sharding=one_chip)
     text = _compile(lambda a: bucket_sort.sort_planned(a, plan), x).as_text()
@@ -239,7 +250,26 @@ def test_mesh_phase_plans_are_native(mesh_25m):
     phases = [plan.run_plan, plan.dealt_plan, plan.sample_plan,
               plan.bucket_plan]
     assert all(p.impl == "pallas" and p.interpret is False for p in phases)
-    assert [p.num_levels for p in phases] == [2, 2, 0, 3]
+    assert [p.num_levels for p in phases] == [2, 2, 0, 2]
+
+
+def test_mesh_relocation_gathers_read_vmem(mesh_25m):
+    """As ``test_default_relocation_gathers_read_vmem``, on the 4-chip
+    cell's program: the run, dealt and bucket phases' two bucket levels
+    each relocate a key word and a payload into 2^23 slots or more, and
+    each of those 12 gathers reads its source from VMEM (``S(1)``).
+    The bucket phase's third level, gone since its level-1 buckets
+    sort directly, read its source from HBM at ~22 ns an element."""
+    _, compiled = mesh_25m
+    big = [(size, src)
+           for size, _, src in _gathers(compiled.as_text(), "relocate")
+           if size >= 1 << 23]
+    assert len(big) == 12, big
+    assert all("S(1)" in src for _, src in big), (
+        "a relocation gather reads its source from HBM in this compile: "
+        "not a wrong result, but likely ~1.65x slower relocation on a "
+        "v5e; measure sort.relocate on the chip with tools/step_times.py",
+        big)
 
 
 def test_mesh_collectives(mesh_25m):
