@@ -103,18 +103,6 @@ def make_distribution(name: str, n: int, rng: np.random.Generator):
     if name == "bucket-killer":
         # many duplicates of a few values — worst case for naive splitters
         return rng.choice(np.array([3, 7, 11], np.int32), n)
-    if name == "nearly-sorted":
-        # sorted data with ~1% random adjacent transpositions: long runs
-        # survive, which is the merge strategy's home turf (DESIGN.md §8)
-        x = np.sort(rng.integers(-(2**31), 2**31 - 1, n).astype(np.int32))
-        idx = rng.integers(0, max(n - 1, 1), max(n // 100, 1))
-        x[idx], x[idx + 1] = x[idx + 1].copy(), x[idx].copy()
-        return x
-    if name == "skewed":
-        # heavy-tailed duplicates (zipf) — low top-bits entropy
-        return (rng.zipf(1.3, n) % (2**31 - 1)).astype(np.int32)
-    if name == "all-dup":
-        return np.full(n, 42, np.int32)
     raise KeyError(name)
 
 

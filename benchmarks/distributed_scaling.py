@@ -54,7 +54,7 @@ def _measure(d: int, n_global: int, repeats: int) -> dict:
         t = timeit(run_fn, x, repeats=repeats)
         out["schedule"] = (
             f"oversample={plan.oversample} c_pair={plan.c_pair} "
-            f"out_cap={plan.out_cap} local={plan.run_plan.root.strategy}"
+            f"out_cap={plan.out_cap}"
         )
     out["us_per_call"] = t * 1e6
     return out

@@ -55,7 +55,6 @@ def main() -> None:
         moe_dispatch,
         sample_size_sweep,
         sort_throughput,
-        strategies,
         topk_partial,
     )
 
@@ -82,8 +81,6 @@ def main() -> None:
             n=262144 if quick else 1048576,
             max_trials=8 if quick else 12,
             repeats=2 if quick else 3),
-        "strategies": lambda: strategies.run(
-            n=262144 if quick else 1048576),
         "distributed": lambda: distributed_scaling.run(
             n_global=65536 if quick else 262144,
             repeats=2 if quick else 3),

@@ -4,10 +4,10 @@
 # multi-chip/pod distributed variant, partial (top-k) sort, and the
 # baselines the paper compares against.
 
-# NOTE: the tuning and probing entries themselves stay namespaced
-# (repro.core.autotune.autotune, repro.core.probe.probe,
-# repro.core.cost_model.estimate) — binding the function name here
-# would shadow the submodule (or read like it does).
+# NOTE: the tuning entries themselves stay namespaced
+# (repro.core.autotune.autotune, repro.core.cost_model.estimate) —
+# binding the function name here would shadow the submodule (or read
+# like it does).
 from repro.core.autotune import (
     AutotuneResult,
     CandidateScore,
@@ -18,7 +18,7 @@ from repro.core.autotune import (
     save_shard_plan,
     shard_plan_for,
 )
-from repro.core.cost_model import COST_MODEL_VERSION, CostBreakdown, Priors
+from repro.core.cost_model import COST_MODEL_VERSION, CostBreakdown
 from repro.core.bucket_sort import (
     argsort,
     argsort_batched,
@@ -45,7 +45,6 @@ from repro.core.guard import (
 )
 from repro.core.key_codec import SUPPORTED_DTYPES, KeyCodec, codec_for
 from repro.core.partial_sort import topk, topk_batched
-from repro.core.probe import probed_config, recommend_strategy
 from repro.core.plan import (
     LevelPlan,
     ShardPlan,
@@ -92,12 +91,9 @@ __all__ = [
     "plan_from_dict",
     "plan_to_dict",
     "resolve_plan",
-    "probed_config",
-    "recommend_strategy",
     "AutotuneResult",
     "CandidateScore",
     "CostBreakdown",
-    "Priors",
     "COST_MODEL_VERSION",
     "plan_for",
     "load_plan",

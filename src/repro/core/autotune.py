@@ -3,10 +3,10 @@ sort-plan space, with a persistent on-disk plan cache.
 
 The planner (``core/plan.py``) makes the schedule explicit data; this
 module picks the BEST schedule for a signature.  The knobs that
-dominate throughput (``tile``, ``s``, ``block_rows``, the fusion
-flags, the relocation mode, the local-sort strategy) must be tuned per
-architecture and input size (Leischner et al.; Casanova et al.), and
-the deterministic pipeline makes every candidate a pure config swap.
+dominate throughput (``tile``, ``s``, ``block_rows``, the relocation
+mode) must be tuned per architecture and input size (Leischner et al.;
+Casanova et al.), and the deterministic pipeline makes every candidate
+a pure config swap.
 
 Search protocol (DESIGN.md §10): every candidate in the space is
 scored by the analytic cost model (``core/cost_model.estimate``), and
@@ -52,7 +52,6 @@ import jax
 import numpy as np
 
 from repro.core import cost_model, faults, guard
-from repro.core import plan as plan_mod
 from repro.core.plan import (
     ShardPlan,
     SortPlan,
@@ -64,7 +63,6 @@ from repro.core.plan import (
     shard_plan_to_dict,
 )
 from repro.core.sort_config import SortConfig, next_pow2
-from repro.kernels import ops
 
 _CACHE_ENV = "REPRO_SORT_PLAN_CACHE"
 _STORE_SCHEMA = "sort_plan_cache/v1"
@@ -246,24 +244,17 @@ def candidate_space(
 
     The BASE config is always candidate 0, so the measured winner is by
     construction at least as fast as the default plan.  The space
-    crosses strategy × tile × s × block_rows × fusion × relocation,
-    nearest neighbours first, deduplicated, truncated to
-    ``max_trials``.  The local-sort strategies (DESIGN.md §8) come
-    right after the base config: they are the highest-variance axis
-    (radix vs merge vs bitonic differ by integer factors across key
-    widths and input distributions).
+    crosses tile × s × block_rows × relocation, nearest neighbours
+    first, deduplicated, truncated to ``max_trials``.
     """
     tiles = [cfg.tile, cfg.tile * 2, max(cfg.tile // 2, 128), cfg.tile * 4]
     svals = [cfg.s, cfg.s * 2, max(cfg.s // 2, 2), cfg.s * 4]
     brs = [cfg.block_rows, 8, 32] if cfg.block_rows is None else [
         cfg.block_rows, None, 8
     ]
-    fusions = [(True, True), (False, False)]
     relocs = ["gather", "scatter"]
     if cfg.relocation != "gather":
         relocs.reverse()
-    if not cfg.fuse_sampling:
-        fusions.reverse()
 
     seen: set[SortConfig] = set()
     out: list[Candidate] = []
@@ -294,9 +285,6 @@ def candidate_space(
         out.append(Candidate(cfg=cand, label=bits or "base"))
 
     _add()  # the base config: candidate 0, the speedup reference
-    for st in ops.strategies_for(*plan_mod.resolve_impl(cfg)):
-        if st != cfg.strategy:
-            _add(strategy=st)
     for t in tiles:
         _add(tile=t)
     for s in svals:
@@ -306,8 +294,6 @@ def candidate_space(
             _add(tile=t, s=s)
     for br in brs:
         _add(block_rows=br)
-    for fs, fr in fusions:
-        _add(fuse_sampling=fs, fuse_ranking=fr)
     for rl in relocs:
         _add(relocation=rl)
     return out
@@ -500,7 +486,6 @@ def autotune(
     warmup: int = 1,
     seed: int = 0,
     measure_budget: int | None = 5,
-    priors: cost_model.Priors | None = None,
     seed_cfgs: tuple[SortConfig, ...] = (),
     denylist: frozenset[str] = frozenset(),
 ) -> AutotuneResult:
@@ -512,9 +497,6 @@ def autotune(
     Args:
         measure_budget: candidates to actually time (None =
             exhaustive).  ValueError if not a positive int or None.
-        priors: distribution priors for the cost model (sortedness,
-            top-bits entropy — see ``core.probe.priors_for``); None
-            uses the uniform-random defaults.
         seed_cfgs: extra configs appended to the candidate space and
             FORCED into the measured set (the cross-shape transfer
             path of :func:`plan_for` passes the nearest cached
@@ -557,7 +539,7 @@ def autotune(
         )
         plans.append(plan)
         try:
-            predicted.append(cost_model.estimate(plan, priors=priors).total)
+            predicted.append(cost_model.estimate(plan).total)
         except Exception as e:  # score as worst — never silently
             warnings.warn(
                 f"cost model failed for candidate {cand.label!r} "
@@ -640,16 +622,11 @@ def _cfg_from_winner_plan(plan: SortPlan, cfg: SortConfig):
     kw: dict = dict(
         plan="default",
         block_rows=node.block_rows,
-        strategy=node.strategy,
-        radix_bits=node.radix_bits,
-        merge_run=node.merge_run,
     )
     if node.kind == "bucket":
         kw.update(
             tile=node.tile,
             s=node.s,
-            fuse_sampling=node.fuse_sampling,
-            fuse_ranking=node.fuse_ranking,
             relocation=node.relocation,
         )
         if node.tile > cfg.direct_max:
@@ -705,7 +682,6 @@ def plan_for(
     max_trials: int = 16,
     repeats: int = 3,
     measure_budget: int | None = 5,
-    priors: cost_model.Priors | None = None,
     transfer: bool = True,
 ) -> SortPlan:
     """Cached-or-tuned plan for a signature (the ``plan="autotune"``
@@ -733,9 +709,9 @@ def plan_for(
         try:
             plan = plan_from_dict(rec["plan"])
         except (ValueError, TypeError):
-            # A record from an older plan schema (e.g. pre-strategy
-            # sort_plan/v1): treat as a clean miss — re-tune below and
-            # overwrite, never misread a stale plan.
+            # A record from an older plan schema (e.g. sort_plan/v3):
+            # treat as a clean miss — re-tune below and overwrite,
+            # never misread a stale plan.
             pass
         else:
             _MEMO[key] = plan
@@ -757,7 +733,7 @@ def plan_for(
     result = autotune(
         length, dtype, cfg, rows=rows, pad_rows=pad_rows,
         max_trials=max_trials, repeats=repeats,
-        measure_budget=budget, priors=priors, seed_cfgs=seed_cfgs,
+        measure_budget=budget, seed_cfgs=seed_cfgs,
         denylist=frozenset(deny),
     )
     if result.failed:
@@ -782,8 +758,8 @@ def plan_for(
 
 
 # ----------------------------------------------------------------------
-# Distributed candidate axis: oversample x local strategy x exchange
-# tiling, persisted in the same JSON store keyed by mesh signature
+# Distributed candidate axis: oversample x exchange tiling, persisted
+# in the same JSON store keyed by mesh signature
 # ----------------------------------------------------------------------
 
 # Process-local memo for tuned shard plans (same role as _MEMO).
@@ -821,28 +797,22 @@ def shard_candidate_space(
 
     The BASE (requested cfg/oversample/pair_align) is candidate 0, so
     the measured winner is never slower than the default schedule.  The
-    axes, nearest first: the per-phase local-sort strategy (highest
-    variance, DESIGN.md §8), the oversample factor c (trades sample
-    volume against the 1/c slack in ``c_pair``), and the exchange
-    tiling ``pair_align`` (lane alignment of the per-pair all_to_all
+    axes, nearest first: the oversample factor c (trades sample volume
+    against the 1/c slack in ``c_pair``) and the exchange tiling
+    ``pair_align`` (lane alignment of the per-pair all_to_all
     capacity).
     """
     seen: set[tuple] = set()
     out: list[ShardCandidate] = []
 
-    def _add(label: str, *, strategy=None, osamp=None, palign=None):
+    cand_cfg = dataclasses.replace(cfg, plan="default")
+
+    def _add(label: str, *, osamp=None, palign=None):
         if len(out) >= max_trials:
             return
         o = oversample if osamp is None else osamp
         pa = pair_align if palign is None else palign
         if o < 1 or o & (o - 1) or pa < 8 or pa & (pa - 1):
-            return
-        try:
-            cand_cfg = dataclasses.replace(
-                cfg, plan="default",
-                **({"strategy": strategy} if strategy else {}),
-            )
-        except ValueError:
             return
         key = (cand_cfg, o, pa)
         if key in seen:
@@ -853,9 +823,6 @@ def shard_candidate_space(
         ))
 
     _add("base")
-    for st in ops.strategies_for(*plan_mod.resolve_impl(cfg)):
-        if st != cfg.strategy:
-            _add(f"strategy={st}", strategy=st)
     for o in (oversample * 2, max(oversample // 2, 1), oversample * 4):
         _add(f"oversample={o}", osamp=o)
     for pa in (128, 256):
@@ -877,7 +844,6 @@ def autotune_shard(
     warmup: int = 1,
     seed: int = 0,
     measure_budget: int | None = 5,
-    priors: cost_model.Priors | None = None,
     seed_candidates: tuple[ShardCandidate, ...] = (),
     denylist: frozenset[str] = frozenset(),
 ) -> AutotuneResult:
@@ -928,7 +894,7 @@ def autotune_shard(
         )
         plans.append(plan)
         try:
-            predicted.append(cost_model.estimate(plan, priors=priors).total)
+            predicted.append(cost_model.estimate(plan).total)
         except Exception as e:  # score as worst — never silently
             warnings.warn(
                 f"cost model failed for distributed candidate "
@@ -1027,19 +993,11 @@ def _nearest_shard_record(
 
 def _shard_seed_from_record(plan: ShardPlan, cfg: SortConfig):
     """Transfer seed for the distributed search: the cached winner's
-    oversample/pair_align plus its run-phase local-sort strategy,
-    applied over the requesting ``cfg``."""
-    node = plan.run_plan.root
-    try:
-        seed_cfg = dataclasses.replace(
-            cfg, plan="default", strategy=node.strategy,
-            radix_bits=node.radix_bits, merge_run=node.merge_run,
-        )
-    except ValueError:
-        return None
+    oversample/pair_align, applied over the requesting ``cfg``."""
     return ShardCandidate(
-        cfg=seed_cfg, oversample=plan.oversample,
-        pair_align=plan.pair_align, label="transfer",
+        cfg=dataclasses.replace(cfg, plan="default"),
+        oversample=plan.oversample, pair_align=plan.pair_align,
+        label="transfer",
     )
 
 
@@ -1056,7 +1014,6 @@ def shard_plan_for(
     max_trials: int = 8,
     repeats: int = 2,
     measure_budget: int | None = 5,
-    priors: cost_model.Priors | None = None,
     transfer: bool = True,
 ) -> ShardPlan:
     """Cached-or-tuned distributed plan (the ``plan="autotune"`` path of
@@ -1103,18 +1060,16 @@ def shard_plan_for(
     if transfer and measure_budget is not None:
         near = _nearest_shard_record(store, base, key)
         if near is not None:
-            seed = _shard_seed_from_record(near[0], cfg)
-            if seed is not None:
-                seeds = (seed,)
-                budget = min(measure_budget, 2)
-                transfer_from = near[1]
+            seeds = (_shard_seed_from_record(near[0], cfg),)
+            budget = min(measure_budget, 2)
+            transfer_from = near[1]
 
     deny = store.get("denylist", {}).get(key, {})
     result = autotune_shard(
         mesh, axt, n_global, dtype, cfg,
         oversample=oversample, pair_align=pair_align,
         max_trials=max_trials, repeats=repeats,
-        measure_budget=budget, priors=priors, seed_candidates=seeds,
+        measure_budget=budget, seed_candidates=seeds,
         denylist=frozenset(deny),
     )
     if result.failed:

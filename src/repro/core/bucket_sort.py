@@ -191,16 +191,15 @@ def _pad_cols(kw, vals, new_len, pad_base):
 
 
 def _direct_sort(kw, vals, node: LevelPlan, impl, interpret, pad_base):
-    """Single-tile local sort of each row (rows, L), L <= direct_max;
-    all geometry (pow2-padded width, kernel block size) AND the
-    local-sort strategy are plan-carried (DESIGN.md §8)."""
+    """Single-tile bitonic sort of each row (rows, L), L <= direct_max;
+    all geometry (pow2-padded width, kernel block size) is
+    plan-carried."""
     length = kw[0].shape[1]
     kw, vals, pad_base = _pad_cols(kw, vals, node.lp, pad_base)
     with jax.named_scope("sort.local_sort"):
         sk, sv = ops.sort_tiles(
             kw, vals, impl=impl, interpret=interpret,
-            block_rows=node.block_rows, strategy=node.strategy,
-            radix_bits=node.radix_bits, merge_run=node.merge_run,
+            block_rows=node.block_rows,
         )
         return tuple(w[:, :length] for w in sk), sv[:, :length], pad_base
 
@@ -463,28 +462,12 @@ def _bucket_round(kw, vals, node: LevelPlan, impl: str, interpret: bool,
     with jax.named_scope("sort.local_sort"):
         tkw = tuple(w.reshape(r * m, t) for w in kw)
         tv = vals.reshape(r * m, t)
-        if node.fuse_sampling:
-            tkw, tv, samp_kw, samp_v = ops.sort_tiles_sample(
-                tkw, tv, num_samples=sper, impl=impl,
-                interpret=interpret, block_rows=node.block_rows,
-                strategy=node.strategy, radix_bits=node.radix_bits,
-                merge_run=node.merge_run,
-            )
-            samples_kw = tuple(w.reshape(r, m * sper) for w in samp_kw)
-            samples_v = samp_v.reshape(r, m * sper)
-        else:
-            tkw, tv = ops.sort_tiles(
-                tkw, tv, impl=impl, interpret=interpret,
-                block_rows=node.block_rows, strategy=node.strategy,
-                radix_bits=node.radix_bits, merge_run=node.merge_run,
-            )
-            samp_idx = (
-                jnp.arange(1, sper + 1, dtype=jnp.int32) * (t // sper)
-            ) - 1
-            samples_kw = tuple(
-                w[:, samp_idx].reshape(r, m * sper) for w in tkw
-            )
-            samples_v = tv[:, samp_idx].reshape(r, m * sper)
+        tkw, tv, samp_kw, samp_v = ops.sort_tiles_sample(
+            tkw, tv, num_samples=sper, impl=impl,
+            interpret=interpret, block_rows=node.block_rows,
+        )
+        samples_kw = tuple(w.reshape(r, m * sper) for w in samp_kw)
+        samples_v = samp_v.reshape(r, m * sper)
 
     # Step 4: sort all samples (recursive; sample array is L*s/T << L).
     sskw, ssv, pad_base = _run_node(
@@ -506,21 +489,10 @@ def _bucket_round(kw, vals, node: LevelPlan, impl: str, interpret: bool,
     with jax.named_scope("sort.partition"):
         spkw_t = tuple(jnp.repeat(w, m, axis=0) for w in spkw)  # (r*m, s_round-1)
         spv_t = jnp.repeat(spv, m, axis=0)
-        if node.fuse_ranking:
-            ranks, counts2 = ops.splitter_partition(
-                tkw, tv, spkw_t, spv_t, impl=impl, interpret=interpret,
-                block_rows=node.part_block_rows,
-            )  # ranks (r*m, s_round-1); counts2 (r*m, s_round)
-        else:
-            ranks = ops.splitter_ranks(
-                tkw, tv, spkw_t, spv_t, impl=impl, interpret=interpret
-            )  # (r*m, s_round-1), values in [0, T]
-            ends = jnp.concatenate(
-                [ranks, jnp.full((r * m, 1), t, jnp.int32)], axis=1
-            )
-            counts2 = ends - jnp.concatenate(
-                [jnp.zeros((r * m, 1), jnp.int32), ranks], axis=1
-            )
+        ranks, counts2 = ops.splitter_partition(
+            tkw, tv, spkw_t, spv_t, impl=impl, interpret=interpret,
+            block_rows=node.part_block_rows,
+        )  # ranks (r*m, s_round-1); counts2 (r*m, s_round)
         starts = jnp.concatenate(
             [jnp.zeros((r * m, 1), jnp.int32), ranks], axis=1
         )  # (r*m, s_round): start of bucket j within tile i

@@ -3,15 +3,15 @@
 The paper's core property — deterministic regular sampling makes every
 bucket capacity a *static guarantee* — has a corollary for tuning: the
 cost of a plan is a function of its **geometry**, not of the data.
-Bytes moved per pass, compare-exchange counts, radix passes, merge
-levels, exchange volume — all are closed-form in the plan fields.  This
-module generalizes DESIGN.md §6's two-word data-movement model into a
-full walk over the plan IR (:func:`estimate` on
-``SortPlan`` / ``TopkPlan`` / ``ShardPlan``), so the autotuner
-(``core/autotune.py``) can score the WHOLE candidate space analytically
-and measure only the top few (the AttentionEngine/roller policy shape;
-the multiway-mergesort analysis arXiv 1702.07961 shows such a
-data-movement model ranks GPU sort variants well).
+Bytes moved per pass, compare-exchange counts, exchange volume — all
+are closed-form in the plan fields.  This module generalizes DESIGN.md
+§6's two-word data-movement model into a full walk over the plan IR
+(:func:`estimate` on ``SortPlan`` / ``TopkPlan`` / ``ShardPlan``), so
+the autotuner (``core/autotune.py``) can score the WHOLE candidate
+space analytically and measure only the top few (the
+AttentionEngine/roller policy shape; the multiway-mergesort analysis
+arXiv 1702.07961 shows such a data-movement model ranks GPU sort
+variants well).
 
 The unit is **HBM byte-equivalents**: one unit = the cost of moving one
 byte between HBM and VMEM.  Compute is folded in at ``OP_BYTE_EQUIV``
@@ -22,13 +22,9 @@ model's *rank* quality against measured times is what the tests pin
 (Spearman over a fixed candidate slice, ``tests/test_cost_model.py``)
 and what ``BENCH_sort.json`` records per candidate.
 
-Distribution priors (DESIGN.md §10): the probe's two signals
-(``core/probe.priors_for``) shift only the strategy-dependent op terms —
-sortedness discounts the merge strategy's compare volume (long runs
-mean cheap formation and shallow effective merging), low top-bits
-entropy penalizes radix (degenerate digit histograms make the rank
-passes skewed).  Geometry terms never depend on data: that is the
-paper's determinism, kept.
+Every term is geometry: the local sort is the data-oblivious bitonic
+network, so no term depends on the data — the paper's determinism,
+kept.
 
 ``COST_MODEL_VERSION`` is persisted with every autotuned store record;
 a version bump makes old records a clean cache miss (re-tune, never
@@ -45,7 +41,7 @@ from repro.core.sort_config import next_pow2
 # Bump on ANY change to the constants or formulas below: persisted
 # autotune records carry this tag and a mismatch at load is a clean
 # re-tune (core/autotune.plan_for / shard_plan_for).
-COST_MODEL_VERSION = "cost_model/v2"
+COST_MODEL_VERSION = "cost_model/v3"
 
 # --- model constants (DESIGN.md §10 derives each; calibrated once
 # against a measured 12-candidate slice at n=2^18, see the Spearman
@@ -58,9 +54,6 @@ COLLECTIVE_BYTE_WEIGHT = 4.0
 # A scatter write costs ~this many gather-write equivalents (DESIGN.md
 # §4: serialized RMW vs dense destination-indexed reads).
 SCATTER_WRITE_FACTOR = 5.0
-# Per-pass per-element radix work: counter update + scan share + rank
-# binary searches (kernels/radix.py); the log term is the slot search.
-RADIX_PASS_BASE = 3.0
 # Splitter ranking compares every element against all s_round-1
 # splitters, one at a time in the partition kernel: per-element units
 # per bucket.
@@ -69,32 +62,9 @@ RANK_UNITS_PER_BUCKET = 2.0
 # bases over the slots (bucket_sort._chunk_values): one cumsum add and
 # one offset add per slot, whatever the chunk count.
 CHUNK_EXPAND_UNITS = 2.0
-# Merge-path per-level per-element work: the diagonal binary search is
-# amortized across each output block (fraction of log2(T) per element)
-# plus the linear merge move.
-MERGE_SEARCH_FRACTION = 0.25
-MERGE_LEVEL_BASE = 2.0
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 LANE = 128
 SUBLANE = 8
-
-
-@dataclasses.dataclass(frozen=True)
-class Priors:
-    """Distribution priors for the strategy-dependent op terms.
-
-    ``sortedness`` — fraction of adjacent pairs already in canonical
-    order (0.5 = random); ``top_bits_entropy`` — Shannon bits (max 8)
-    of the top byte of the most significant canonical word.  Defaults
-    are the data-free neutral assumptions (random keys, full entropy);
-    ``core/probe.priors_for`` measures both on a concrete sample.
-    """
-
-    sortedness: float = 0.5
-    top_bits_entropy: float = 8.0
-
-
-DEFAULT_PRIORS = Priors()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,8 +73,8 @@ class CostBreakdown:
 
     Attributes:
         hbm_bytes: HBM<->VMEM bytes moved across every pass of the plan.
-        op_units: compare-unit count (compare-exchanges, radix pass
-            work, merge-path searches) across every level.
+        op_units: compare-unit count (compare-exchanges, splitter
+            ranking, slot-source expansion) across every level.
         collective_bytes: per-device interconnect bytes (shard plans:
             deal + sample gather + c_pair-padded bucket exchange; 0 for
             single-device plans).
@@ -139,45 +109,10 @@ def _stages(width: int) -> int:
     return lg * (lg + 1) // 2
 
 
-def local_sort_op_units(
-    width: int,
-    num_words: int,
-    strategy: str,
-    radix_bits: int,
-    merge_run: int,
-    priors: Priors,
-) -> float:
-    """Per-ELEMENT compare-unit cost of one local sort of ``width``
-    (DESIGN.md §10's strategy table).
-
-    bitonic: ``stages(T) * (w+1)`` — data-oblivious, priors never apply.
-    radix: ``w * 32/bits`` passes at ``RADIX_PASS_BASE + log2(T)/4``
-        units each, scaled up as top-bits entropy drops (degenerate
-        digit histograms).
-    merge: run formation ``stages(r)`` plus ``log2(T/r)`` merge-path
-        levels at ``MERGE_SEARCH_FRACTION*log2(T) + MERGE_LEVEL_BASE``
-        units, all ``*(w+1)``, discounted as sortedness rises above 0.5
-        (runs pre-exist).
-    """
-    wfac = num_words + 1  # key words + the payload tiebreak word
-    lg = _log2(width)
-    if strategy == "radix":
-        passes = num_words * (32 // radix_bits)
-        per_pass = RADIX_PASS_BASE + lg / 4.0
-        entropy = min(max(priors.top_bits_entropy, 0.0), 8.0)
-        skew = 2.0 - entropy / 8.0  # 1.0 at full entropy, 2.0 degenerate
-        return passes * per_pass * skew
-    if strategy == "merge":
-        r = min(next_pow2(merge_run), next_pow2(width))
-        lr = _log2(r)
-        form = _stages(r)
-        levels = max(lg - lr, 0)
-        merge = levels * (MERGE_SEARCH_FRACTION * lg + MERGE_LEVEL_BASE)
-        p = min(max(priors.sortedness, 0.0), 1.0)
-        # no discount at/below random (0.5); 0.3x at fully sorted
-        discount = 1.0 - 1.4 * max(p - 0.5, 0.0)
-        return (form + merge) * wfac * discount
-    return _stages(width) * wfac  # bitonic
+def local_sort_op_units(width: int, num_words: int) -> float:
+    """Per-ELEMENT compare-unit cost of one bitonic sort of ``width``:
+    ``stages(T) * (w+1)`` — data-oblivious (DESIGN.md §10)."""
+    return _stages(width) * (num_words + 1)  # key words + payload tiebreak
 
 
 def _node_vmem(node: LevelPlan, bpe: int) -> int:
@@ -202,7 +137,7 @@ def _align_factor(node: LevelPlan) -> float:
 
 
 def _estimate_node(
-    node: LevelPlan | None, nw: int, priors: Priors
+    node: LevelPlan | None, nw: int
 ) -> tuple[float, float, int, float]:
     """(hbm_bytes, op_units, vmem_peak, align_penalty) of a level tree."""
     if node is None:
@@ -211,30 +146,20 @@ def _estimate_node(
     if node.kind == "direct":
         e = node.rows * node.lp
         hbm = 2.0 * e * bpe  # one read + one write
-        ops = e * local_sort_op_units(
-            node.lp, nw, node.strategy, node.radix_bits, node.merge_run,
-            priors,
-        )
+        ops = e * local_sort_op_units(node.lp, nw)
         return hbm, ops, _node_vmem(node, bpe), _align_factor(node)
 
     e = node.elements          # rows * lp entering the round
     eb = node.bucket_elements  # rows * s_round * cap after relocation
     # Step 2 local tile sort: one read + one write per element.
     hbm = 2.0 * e * bpe
-    ops = e * local_sort_op_units(
-        node.tile, nw, node.strategy, node.radix_bits, node.merge_run,
-        priors,
-    )
-    # Step 3 sampling: fused = kernel epilogue (free); unfused = one
-    # more pass over the sorted tiles.
-    if not node.fuse_sampling:
-        hbm += e * bpe
-    # Steps 6-7 splitter ranking/partition: fused = one read of the
-    # tiles; unfused = a ranks pass plus a partition pass.  Ranking
+    ops = e * local_sort_op_units(node.tile, nw)
+    # Step 3 sampling is the tile sort's epilogue: no extra pass.
+    # Steps 6-7 splitter partition: one read of the tiles.  Ranking
     # compares every element against all s_round-1 splitters, one at a
     # time in the partition kernel — LINEAR in the bucket count, which
     # is what prices the s knob.
-    hbm += (1.0 if node.fuse_ranking else 2.0) * e * bpe
+    hbm += e * bpe
     ops += e * node.s_round * RANK_UNITS_PER_BUCKET * (nw + 1)
     # Step 8 relocation into the dense bucket array, then compaction.
     if node.relocation == "scatter":
@@ -247,7 +172,7 @@ def _estimate_node(
     align = _align_factor(node)
 
     for child in (node.sample_plan, node.bucket_plan):
-        ch, co, cv, ca = _estimate_node(child, nw, priors)
+        ch, co, cv, ca = _estimate_node(child, nw)
         hbm += ch
         ops += co
         vmem = max(vmem, cv)
@@ -271,32 +196,23 @@ def _finish(
     )
 
 
-def _estimate_sort(plan: SortPlan, priors: Priors) -> CostBreakdown:
-    hbm, ops, vmem, align = _estimate_node(plan.root, plan.num_words, priors)
+def _estimate_sort(plan: SortPlan) -> CostBreakdown:
+    hbm, ops, vmem, align = _estimate_node(plan.root, plan.num_words)
     return _finish(hbm, ops, 0.0, vmem, align)
 
 
-def _estimate_topk(plan: TopkPlan, priors: Priors) -> CostBreakdown:
+def _estimate_topk(plan: TopkPlan) -> CostBreakdown:
     nw, bpe = _topk_words(plan), 4 * (_topk_words(plan) + 1)
     if plan.length <= plan.direct_max:
         e = max(plan.rows, 1) * next_pow2(plan.length)
-        ops = e * local_sort_op_units(
-            plan.length, nw, plan.strategy, plan.radix_bits,
-            plan.merge_run, priors,
-        )
+        ops = e * local_sort_op_units(plan.length, nw)
         return _finish(2.0 * e * bpe, ops, 0.0, 0, 1.0)
     e = plan.elements
     ec = plan.candidate_elements
     # tile sort + threshold pass + candidate pack + candidate sort
     hbm = 2.0 * e * bpe + e * bpe + (e + ec) * bpe + 2.0 * ec * bpe
-    ops = e * local_sort_op_units(
-        plan.tile, nw, plan.strategy, plan.radix_bits, plan.merge_run,
-        priors,
-    )
-    ops += ec * local_sort_op_units(
-        plan.ccap, nw, plan.strategy, plan.radix_bits, plan.merge_run,
-        priors,
-    )
+    ops = e * local_sort_op_units(plan.tile, nw)
+    ops += ec * local_sort_op_units(plan.ccap, nw)
     vmem = 0
     if plan.block_rows is not None:
         vmem = 2 * plan.block_rows * plan.tile * bpe
@@ -309,23 +225,13 @@ def _topk_words(plan: TopkPlan) -> int:
     return getattr(plan, "num_words", 1)
 
 
-def _estimate_shard(plan: ShardPlan, priors: Priors) -> CostBreakdown:
+def _estimate_shard(plan: ShardPlan) -> CostBreakdown:
     bpe = 4 * (plan.num_words + 1)
     hbm = ops = 0.0
     vmem, align = 0, 1.0
-    # The dealt/bucket phases sort concatenations of d sorted runs —
-    # structurally high sortedness regardless of the input data.
-    piecewise = dataclasses.replace(
-        priors, sortedness=max(priors.sortedness, 0.75)
-    )
-    for name, pri in (
-        ("run_plan", priors),
-        ("dealt_plan", piecewise),
-        ("sample_plan", piecewise),
-        ("bucket_plan", piecewise),
-    ):
+    for name in ("run_plan", "dealt_plan", "sample_plan", "bucket_plan"):
         sub: SortPlan = getattr(plan, name)
-        b = _estimate_sort(sub, pri)
+        b = _estimate_sort(sub)
         hbm += b.hbm_bytes
         ops += b.op_units
         vmem = max(vmem, b.vmem_peak_bytes)
@@ -340,20 +246,17 @@ def _estimate_shard(plan: ShardPlan, priors: Priors) -> CostBreakdown:
     return _finish(hbm, ops, coll, vmem, align)
 
 
-def estimate(plan, priors: Priors | None = None) -> CostBreakdown:
+def estimate(plan) -> CostBreakdown:
     """Analytic cost of a plan node — the autotuner's ranking score.
 
-    Deterministic and pure: equal ``(plan, priors)`` give equal
-    breakdowns; cost is positive and monotone in n for fixed config
-    geometry (property-tested in ``tests/test_cost_model.py``).
+    Deterministic and pure: equal plans give equal breakdowns; cost is
+    positive and monotone in n for fixed config geometry
+    (property-tested in ``tests/test_cost_model.py``).
 
     Args:
         plan: a :class:`~repro.core.plan.SortPlan`,
             :class:`~repro.core.plan.TopkPlan` or
             :class:`~repro.core.plan.ShardPlan`.
-        priors: optional distribution priors
-            (``core/probe.priors_for``); ``None`` = neutral
-            :data:`DEFAULT_PRIORS`.
     Returns:
         A :class:`CostBreakdown`; rank candidates by ``.total``.
     Raises:
@@ -369,13 +272,12 @@ def estimate(plan, priors: Priors | None = None) -> CostBreakdown:
         >>> (small.total > 0, big.total > small.total)
         (True, True)
     """
-    priors = DEFAULT_PRIORS if priors is None else priors
     if isinstance(plan, SortPlan):
-        return _estimate_sort(plan, priors)
+        return _estimate_sort(plan)
     if isinstance(plan, TopkPlan):
-        return _estimate_topk(plan, priors)
+        return _estimate_topk(plan)
     if isinstance(plan, ShardPlan):
-        return _estimate_shard(plan, priors)
+        return _estimate_shard(plan)
     raise TypeError(
         f"estimate() takes a SortPlan, TopkPlan or ShardPlan, got "
         f"{type(plan).__name__}"

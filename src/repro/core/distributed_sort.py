@@ -41,8 +41,7 @@ jit'd entry takes ``(mesh, plan)`` as STATIC arguments: equal
 ``(shape, mesh, dtype, plan)`` signatures share one compiled
 executable (``trace_count`` exposes the counter; tests assert
 trace-once / zero-retrace discipline exactly as the single-device path
-does).  The per-phase plans inherit the strategy dispatch (DESIGN.md
-§8), so shards can radix- or merge-sort their local runs.
+does).
 
 NAMES IN A TRACE: the four local sorts run under ``sort.phase_run``,
 ``sort.phase_dealt``, ``sort.phase_sample`` and ``sort.phase_bucket``,

@@ -69,8 +69,6 @@ def _sort_small(kw, v1, tplan: TopkPlan):
     skw, sv = ops.sort_tiles(
         *_pad_pow2(tuple(w[None] for w in kw), v1[None]),
         impl=tplan.impl, interpret=tplan.interpret,
-        strategy=tplan.strategy, radix_bits=tplan.radix_bits,
-        merge_run=tplan.merge_run,
     )
     return tuple(w[0, :n] for w in skw), sv[0, :n]
 
@@ -98,8 +96,7 @@ def _smallest_k(kw, tplan: TopkPlan):
     tkw, tv = ops.sort_tiles(
         tuple(w.reshape(m, t) for w in kw), vals.reshape(m, t),
         impl=tplan.impl, interpret=tplan.interpret,
-        block_rows=tplan.block_rows, strategy=tplan.strategy,
-        radix_bits=tplan.radix_bits, merge_run=tplan.merge_run,
+        block_rows=tplan.block_rows,
     )
 
     # steps 3-5: samples -> sorted samples -> s-1 splitters
@@ -243,8 +240,7 @@ def _sort_small_rows(kw, v2, tplan: TopkPlan):
     n = kw[0].shape[1]
     skw, sv = ops.sort_tiles(
         *_pad_pow2(kw, v2), impl=tplan.impl, interpret=tplan.interpret,
-        block_rows=tplan.raw_block_rows, strategy=tplan.strategy,
-        radix_bits=tplan.radix_bits, merge_run=tplan.merge_run,
+        block_rows=tplan.raw_block_rows,
     )
     return tuple(w[:, :n] for w in skw), sv[:, :n]
 
@@ -275,8 +271,7 @@ def _smallest_k_rows(kw, tplan: TopkPlan):
     tkw, tv = ops.sort_tiles(
         tuple(w.reshape(b * m, t) for w in kw), vals.reshape(b * m, t),
         impl=tplan.impl, interpret=tplan.interpret,
-        block_rows=tplan.block_rows, strategy=tplan.strategy,
-        radix_bits=tplan.radix_bits, merge_run=tplan.merge_run,
+        block_rows=tplan.block_rows,
     )
 
     # steps 3-5: per-row samples -> sorted sample rows -> s-1 splitters

@@ -75,14 +75,8 @@ class LevelPlan:
         cap: static per-bucket capacity — the paper's regular-sampling
             bound round_up(lp/s_round + lp/s, 128) (DESIGN.md §2).
         part_block_rows: resolved block size of the fused
-            splitter-partition kernel (None when unfused / xla).
-        fuse_sampling / fuse_ranking / relocation: per-level pipeline
-            choices (today uniform across levels, copied from cfg).
-        strategy: the level's local-sort algorithm ("bitonic" | "radix"
-            | "merge") — a PER-LEVEL plan field (DESIGN.md §8); the
-            executor dispatches ``ops.sort_tiles`` on it.
-        radix_bits / merge_run: strategy knobs carried alongside
-            (consulted only by the matching strategy).
+            splitter-partition kernel (None on xla).
+        relocation: step 8's formulation, copied from cfg.
         compact_block: lanes W of the blocks step 9's gather compaction
             moves whole (DESIGN.md §4): ``min(COMPACT_BLOCK, tile)``,
             which divides ``lp`` (a multiple of the power-of-two tile)
@@ -104,12 +98,7 @@ class LevelPlan:
     s_round: int = 0
     cap: int = 0
     part_block_rows: int | None = None
-    fuse_sampling: bool = False
-    fuse_ranking: bool = False
     relocation: str = "gather"
-    strategy: str = "bitonic"
-    radix_bits: int = 4
-    merge_run: int = 512
     compact_block: int = 0
     sample_plan: "LevelPlan | None" = None
     bucket_plan: "LevelPlan | None" = None
@@ -255,7 +244,7 @@ class SortPlan:
             if node.kind == "direct":
                 lines.append(
                     f"  L{depth}: direct rows={node.rows} lp={node.lp} "
-                    f"block_rows={node.block_rows} strategy={node.strategy}"
+                    f"block_rows={node.block_rows}"
                 )
                 break
             lines.append(
@@ -263,7 +252,7 @@ class SortPlan:
                 f"tile={node.tile} s={node.s} m={node.m} "
                 f"s_round={node.s_round} cap={node.cap} "
                 f"block_rows={node.block_rows} reloc={node.relocation} "
-                f"compact_block={node.compact_block} strategy={node.strategy}"
+                f"compact_block={node.compact_block}"
             )
             node = node.bucket_plan
             depth += 1
@@ -300,16 +289,8 @@ def resolve_impl(cfg: SortConfig) -> tuple[str, bool]:
 
 
 def _resolve_backend(cfg: SortConfig) -> tuple[str, bool, str]:
-    """(impl, interpret, backend) with the cfg Nones resolved.
-
-    Raises:
-        ValueError: naming ``cfg.strategy`` when it has no native TPU
-            kernel and the plan would run Pallas without interpret mode.
-    """
-    from repro.kernels import ops
-
+    """(impl, interpret, backend) with the cfg Nones resolved."""
     impl, interpret = resolve_impl(cfg)
-    ops.check_strategy(cfg.strategy, impl, interpret)
     return impl, interpret, jax.default_backend()
 
 
@@ -341,9 +322,6 @@ def _build_node(
             length=length,
             lp=lp,
             block_rows=_sort_block_rows(impl, rows, lp, cfg.block_rows, nw),
-            strategy=cfg.strategy,
-            radix_bits=cfg.radix_bits,
-            merge_run=cfg.merge_run,
         )
 
     t, sper = cfg.tile, cfg.s
@@ -355,7 +333,7 @@ def _build_node(
     cap = round_up(lp // s_round + lp // sper, 128)
     compact_block = min(COMPACT_BLOCK, t) if cfg.relocation == "gather" else 0
     part_block_rows = None
-    if impl == "pallas" and cfg.fuse_ranking:
+    if impl == "pallas":
         from repro.kernels import splitter
 
         part_block_rows = splitter.partition_block_rows(
@@ -373,12 +351,7 @@ def _build_node(
         s_round=s_round,
         cap=cap,
         part_block_rows=part_block_rows,
-        fuse_sampling=cfg.fuse_sampling,
-        fuse_ranking=cfg.fuse_ranking,
         relocation=cfg.relocation,
-        strategy=cfg.strategy,
-        radix_bits=cfg.radix_bits,
-        merge_run=cfg.merge_run,
         compact_block=compact_block,
         sample_plan=_build_node(rows, m * sper, cfg, impl, nw, depth + 1),
         bucket_plan=_build_node(
@@ -511,10 +484,6 @@ class TopkPlan:
             BOUND for the small sample/candidate sorts (whose padded
             widths the kernels clamp against).
         direct_max: lengths up to this take the direct single-tile path.
-        strategy / radix_bits / merge_run: the local-sort strategy for
-            the tile/candidate sorts, copied from the cfg (DESIGN.md
-            §8; the candidate packs preserve the payload invariant the
-            non-bitonic strategies rely on).
         impl / interpret / backend: resolved as in :class:`SortPlan`.
     """
 
@@ -533,9 +502,6 @@ class TopkPlan:
     impl: str
     interpret: bool
     backend: str
-    strategy: str = "bitonic"
-    radix_bits: int = 4
-    merge_run: int = 512
 
     @property
     def elements(self) -> int:
@@ -579,9 +545,6 @@ def _assemble_topk_plan(
         impl=impl,
         interpret=interpret,
         backend=backend,
-        strategy=cfg.strategy,
-        radix_bits=cfg.radix_bits,
-        merge_run=cfg.merge_run,
     )
 
 
@@ -737,9 +700,7 @@ class ShardPlan:
         sample_plan: replicated sort of the (1, d*s_loc) gathered
             samples.
         bucket_plan: phase-7 local sort of the received (1, d*c_pair)
-            buckets.  Each is a full :class:`SortPlan` and inherits the
-            per-level strategy dispatch (DESIGN.md §8), so shards can
-            e.g. radix-sort their local runs.
+            buckets.  Each is a full :class:`SortPlan`.
     """
 
     axis: tuple[str, ...]
@@ -828,8 +789,7 @@ class ShardPlan:
         for name in ("run_plan", "dealt_plan", "sample_plan", "bucket_plan"):
             sub: SortPlan = getattr(self, name)
             lines.append(
-                f"  {name}: length={sub.length} levels={sub.num_levels} "
-                f"strategy={sub.root.strategy}"
+                f"  {name}: length={sub.length} levels={sub.num_levels}"
             )
         return "\n".join(lines)
 
@@ -942,12 +902,13 @@ def build_shard_plan(
 # Serialization: byte-stable dict/JSON round-trip for the plan cache
 # ----------------------------------------------------------------------
 
-# v2: LevelPlan grew the per-level strategy fields (strategy /
-# radix_bits / merge_run).  v3: LevelPlan grew ``compact_block``.
-# Older records fail plan_from_dict with a ValueError, which the
-# autotune store treats as a clean cache miss (re-tune and overwrite)
+# v3: LevelPlan grew ``compact_block``.  v4: LevelPlan and TopkPlan
+# lost the fields that chose among local sorts and the unfused
+# sampling/ranking steps (one local sort, always fused).  Older records
+# fail plan_from_dict with a ValueError, which the autotune store treats
+# as a clean cache miss (re-tune and overwrite)
 # — never a silently misread plan.
-_SCHEMA = "sort_plan/v3"
+_SCHEMA = "sort_plan/v4"
 
 
 def _node_to_dict(node: LevelPlan | None):

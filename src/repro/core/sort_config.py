@@ -56,12 +56,6 @@ class SortConfig:
         a power of two and acts as an UPPER BOUND — recursion levels
         whose tile count it does not divide clamp down to the largest
         power-of-two divisor (bitonic.effective_block_rows).
-    fuse_sampling: emit Step 3's equidistant samples from the tile-sort
-        kernel epilogue instead of a separate gather over the sorted
-        tiles (one fewer HBM read).
-    fuse_ranking: use the fused Step 6+7 splitter-partition epilogue
-        (ranks + bucket counts in one read) instead of the standalone
-        ranks kernel.
     relocation: "gather" (default) computes the SOURCE index of every
         destination slot and relocates/compacts with `take` — no
         scatters anywhere on the hot path (DESIGN.md §4).  "scatter" is
@@ -84,23 +78,6 @@ class SortConfig:
             runs the tuning search and records the winner);
           * any other string — a path to a plan file saved by
             ``autotune.save_plan``; its signature must match the call.
-    strategy: local-sort algorithm for the tile/direct sorts (DESIGN.md
-        §8).  "bitonic" (default) is the paper's branch-free network;
-        "radix" is an LSD radix rank-gather over the canonical uint32
-        key words (scatter-free, stable); "merge" forms sorted runs and
-        merges them pairwise with merge-path diagonal partitioning
-        (exploits pre-sorted input).  All three produce the identical
-        stable order (tested); the planner carries the choice per level
-        and ``core/autotune`` searches across strategies.  A cheap
-        data-distribution probe (``core/probe.py``) can pick this knob
-        from a concrete input sample without running the tuner.
-    radix_bits: digit width of the radix strategy, in {1, 2, 4} bits
-        (4 = 16 digits per pass, 8 passes per 32-bit key word).  Only
-        consulted when ``strategy == "radix"``.
-    merge_run: initial sorted-run length of the merge strategy, a power
-        of two >= 2 (runs are formed with the bitonic network, then
-        pairwise-merged up to the tile width).  Only consulted when
-        ``strategy == "merge"``.
     row_pad: batch-aware block_rows auto-pick (DESIGN.md §5).  The
         batched entry points (``sort_batched``, ``segment_sort``) pad
         the row count up to a multiple of this power of two before
@@ -130,15 +107,10 @@ class SortConfig:
     impl: str | None = None
     interpret: bool | None = None
     block_rows: int | None = None
-    fuse_sampling: bool = True
-    fuse_ranking: bool = True
     relocation: str = "gather"
     descending: bool = False
     row_pad: int = 8
     plan: str = "default"
-    strategy: str = "bitonic"
-    radix_bits: int = 4
-    merge_run: int = 512
     check: str = "off"
 
     def __post_init__(self):
@@ -182,17 +154,6 @@ class SortConfig:
                 f"got {self.relocation!r}"
             )
         _pow2("row_pad", self.row_pad, 1)
-        if self.strategy not in ("bitonic", "radix", "merge"):
-            raise ValueError(
-                'SortConfig.strategy must be "bitonic", "radix" or '
-                f'"merge", got {self.strategy!r}'
-            )
-        if self.radix_bits not in (1, 2, 4):
-            raise ValueError(
-                f"SortConfig.radix_bits must be 1, 2 or 4, got "
-                f"{self.radix_bits!r}"
-            )
-        _pow2("merge_run", self.merge_run, 2)
         if not (isinstance(self.plan, str) and self.plan):
             raise ValueError(
                 'SortConfig.plan must be "default", "autotune", or a '

@@ -244,18 +244,16 @@ def compiler_params() -> pltpu.CompilerParams:
     )
 
 
-def _block_kernel(*refs, num_words: int, block_rows: int, sort_rows):
+def _block_kernel(*refs, num_words: int, block_rows: int):
     """Kernel body: refs = num_words+1 inputs (key words + vals), then
-    num_words+1 outputs.  ``sort_rows`` is the row-sort network applied
-    to the VMEM block — the bitonic network by default; the radix-rank
-    and merge-path strategies (kernels/radix.py, kernels/merge.py) plug
-    theirs in (DESIGN.md §8)."""
+    num_words+1 outputs; the bitonic network sorts each row of the VMEM
+    block."""
     nw1 = num_words + 1
     in_refs, out_refs = refs[:nw1], refs[nw1:]
 
     def body(rows):
         words = tuple(r[rows, :] for r in in_refs[:num_words])
-        words, vals = sort_rows(words, in_refs[num_words][rows, :])
+        words, vals = bitonic_network_rows(words, in_refs[num_words][rows, :])
         for r, w in zip(out_refs, as_words(words) + (vals,)):
             r[rows, :] = w
 
@@ -263,20 +261,15 @@ def _block_kernel(*refs, num_words: int, block_rows: int, sort_rows):
 
 
 def tile_sort_call(words, vals, num_samples: int, block_rows,
-                   interpret: bool, sort_rows=None,
-                   name: str = "bitonic_tile_sort"):
-    """Shared row-blocked pallas launch for every local-sort strategy:
-    grid over (block_rows, T) blocks, then the optional Step-3 samples.
-    ``sort_rows(words_tuple, vals) -> (words, vals)`` sorts each row of
-    the block; None selects the bitonic network.  ``name`` names the
-    kernel in compiled code and traces (after the strategy).
+                   interpret: bool):
+    """Row-blocked pallas launch of the bitonic tile sort: grid over
+    (block_rows, T) blocks, then the optional Step-3 samples.  The
+    kernel is named ``bitonic_tile_sort`` in compiled code and traces.
 
     Sample j of a sorted row is element (j+1)*T/s - 1, the last element
     of chunk j.  Mosaic refuses every in-kernel form of that lane-strided
     read (strided value slice, strided ref load, (s, T/s) reshape), so
     the samples are a strided XLA slice of the kernel's output."""
-    if sort_rows is None:
-        sort_rows = bitonic_network_rows
     nw = len(words)
     m, t = words[0].shape
     assert vals.shape == (m, t)
@@ -285,10 +278,7 @@ def tile_sort_call(words, vals, num_samples: int, block_rows,
     block_rows = effective_block_rows(m, t, block_rows, num_words=nw)
     blk = pl.BlockSpec((block_rows, t), lambda i: (i, 0))
     out = pl.pallas_call(
-        functools.partial(
-            _block_kernel, num_words=nw, block_rows=block_rows,
-            sort_rows=sort_rows,
-        ),
+        functools.partial(_block_kernel, num_words=nw, block_rows=block_rows),
         grid=row_grid(m, block_rows),
         in_specs=[blk] * (nw + 1),
         out_specs=[blk] * (nw + 1),
@@ -296,7 +286,7 @@ def tile_sort_call(words, vals, num_samples: int, block_rows,
         + [jax.ShapeDtypeStruct((m, t), jnp.int32)],
         compiler_params=compiler_params(),
         interpret=interpret,
-        name=name,
+        name="bitonic_tile_sort",
     )(*words, vals)
     if not num_samples:
         return out

@@ -24,41 +24,10 @@ import jax.numpy as jnp
 from repro.core import faults
 from repro.core.key_codec import codec_for
 from repro.kernels import bitonic as _bitonic
-from repro.kernels import merge as _merge
-from repro.kernels import radix as _radix
 from repro.kernels import ref as _ref
 from repro.kernels import splitter as _splitter
 from repro.kernels import topk as _topk
 from repro.kernels.bitonic import as_words
-
-_STRATEGIES = ("bitonic", "radix", "merge")
-# Strategies whose row sort Mosaic lowers.  The radix and merge row sorts
-# gather data-dependently inside the kernel (``take_along_axis``), which
-# Mosaic refuses; they run in interpret mode and on the xla path only.
-_NATIVE_STRATEGIES = ("bitonic",)
-
-
-def strategies_for(impl: str, interpret: bool) -> tuple[str, ...]:
-    """Local-sort strategies that can run under (impl, interpret)."""
-    if impl == "pallas" and not interpret:
-        return _NATIVE_STRATEGIES
-    return _STRATEGIES
-
-
-def check_strategy(strategy: str, impl: str, interpret: bool) -> None:
-    """Raise ValueError naming ``strategy`` unless it can run under the
-    resolved (impl, interpret) pair."""
-    if strategy not in _STRATEGIES:
-        raise ValueError(
-            f"unknown local-sort strategy {strategy!r}; "
-            f"expected one of {_STRATEGIES}"
-        )
-    if strategy not in strategies_for(impl, interpret):
-        raise ValueError(
-            f"local-sort strategy {strategy!r} has no native TPU kernel "
-            "(its row sort gathers inside the kernel, which Mosaic cannot "
-            "lower); use strategy='bitonic', impl='xla', or interpret=True"
-        )
 
 
 def default_interpret() -> bool:
@@ -134,11 +103,9 @@ def sort_tiles(
     impl: str | None = None,
     interpret: bool | None = None,
     block_rows: int | None = None,
-    strategy: str = "bitonic",
-    radix_bits: int = 4,
-    merge_run: int = 512,
 ):
-    """Sort each row of (m, T) canonical keys (+int32 payload).
+    """Sort each row of (m, T) canonical keys (+int32 payload) with the
+    bitonic network (DESIGN.md §8).
 
     Args:
         keys: (m, T) uint32 word array or tuple of word arrays (msw
@@ -148,39 +115,17 @@ def sort_tiles(
         interpret: Pallas interpret mode (None = auto: True off-TPU).
         block_rows: tiles per grid program on the pallas path (None =
             auto VMEM fill, see bitonic.auto_block_rows); ignored on xla.
-        strategy: local-sort algorithm — "bitonic" (network), "radix"
-            (LSD rank-gather, kernels/radix.py) or "merge" (merge-path,
-            kernels/merge.py).  DESIGN.md §8; the non-bitonic
-            strategies are STABLE key-words-only sorts and require
-            payloads increasing within equal keys (the pipeline
-            invariant; arange payload rows satisfy it).
-        radix_bits / merge_run: strategy knobs (see SortConfig).
     Returns:
         (sorted keys in the input structure, sorted vals), each row
         lexicographically ascending on (*words, payload).
     """
     impl = impl or default_impl()
     interpret = default_interpret() if interpret is None else interpret
-    check_strategy(strategy, impl, interpret)
     faults.check("kernel.launch")  # trace-time chaos site (DESIGN.md §11)
     if impl == "pallas":
-        if strategy == "radix":
-            return _radix.sort_tiles_kv(
-                keys, vals, radix_bits=radix_bits, block_rows=block_rows,
-                interpret=interpret,
-            )
-        if strategy == "merge":
-            return _merge.sort_tiles_kv(
-                keys, vals, merge_run=merge_run, block_rows=block_rows,
-                interpret=interpret,
-            )
         return _bitonic.sort_tiles_kv(
             keys, vals, block_rows=block_rows, interpret=interpret
         )
-    if strategy == "radix":
-        return _radix.composite_sort_rows(keys, vals)
-    if strategy == "merge":
-        return _merge.hybrid_sort_rows(keys, vals, merge_run=merge_run)
     return _ref.sort_tiles_kv(keys, vals)
 
 
@@ -192,49 +137,26 @@ def sort_tiles_sample(
     impl: str | None = None,
     interpret: bool | None = None,
     block_rows: int | None = None,
-    strategy: str = "bitonic",
-    radix_bits: int = 4,
-    merge_run: int = 512,
 ):
     """Fused Steps 2+3: sorted (m, T) tiles plus the s equidistant
     per-tile samples, from one read of the tiles.
 
     Args:
-        As :func:`sort_tiles` (including ``strategy``), plus
-        ``num_samples`` (must divide T).
+        As :func:`sort_tiles`, plus ``num_samples`` (must divide T).
     Returns:
         (sorted_keys, sorted_vals, sample_keys (m, s), sample_vals) —
         keys in the input structure.
     """
     impl = impl or default_impl()
     interpret = default_interpret() if interpret is None else interpret
-    check_strategy(strategy, impl, interpret)
     faults.check("kernel.launch")  # trace-time chaos site (DESIGN.md §11)
     if impl == "pallas":
-        if strategy == "radix":
-            return _radix.sort_tiles_sample_kv(
-                keys, vals, num_samples=num_samples, radix_bits=radix_bits,
-                block_rows=block_rows, interpret=interpret,
-            )
-        if strategy == "merge":
-            return _merge.sort_tiles_sample_kv(
-                keys, vals, num_samples=num_samples, merge_run=merge_run,
-                block_rows=block_rows, interpret=interpret,
-            )
         return _bitonic.sort_tiles_sample_kv(
             keys,
             vals,
             num_samples=num_samples,
             block_rows=block_rows,
             interpret=interpret,
-        )
-    if strategy == "radix":
-        return _radix.composite_sort_sample_rows(
-            keys, vals, num_samples=num_samples
-        )
-    if strategy == "merge":
-        return _merge.hybrid_sort_sample_rows(
-            keys, vals, num_samples=num_samples, merge_run=merge_run
         )
     return _ref.sort_tiles_sample_kv(keys, vals, num_samples=num_samples)
 

@@ -13,15 +13,15 @@ import pytest
 
 from repro.core.sort_config import SortConfig
 
-_XLA = SortConfig(tile=256, s=16, direct_max=512, impl="xla")
-_PAL = SortConfig(tile=128, s=8, direct_max=256, impl="pallas", interpret=True)
+XLA = SortConfig(tile=256, s=16, direct_max=512, impl="xla")
+PAL = SortConfig(tile=128, s=8, direct_max=256, impl="pallas", interpret=True)
 
 CELLS = [
-    pytest.param(_XLA, id="xla-gather"),
-    pytest.param(dataclasses.replace(_XLA, relocation="scatter"),
+    pytest.param(XLA, id="xla-gather"),
+    pytest.param(dataclasses.replace(XLA, relocation="scatter"),
                  id="xla-scatter"),
-    pytest.param(_PAL, id="pallas-gather"),
-    pytest.param(dataclasses.replace(_PAL, relocation="scatter"),
+    pytest.param(PAL, id="pallas-gather"),
+    pytest.param(dataclasses.replace(PAL, relocation="scatter"),
                  id="pallas-scatter"),
 ]
 

@@ -2,7 +2,7 @@
 
 Covers:
   * rank correlation: cost-model scores vs measured wall time over a
-    FIXED 12-candidate slice of distinct plan geometries at n=2^18
+    FIXED 9-candidate slice of distinct plan geometries at n=2^18
     (Spearman >= 0.6; the model needs to RANK, not predict micros);
   * properties (hypothesis when installed, seeded fallback otherwise):
     ``estimate`` is deterministic, strictly positive, and monotone in n
@@ -24,20 +24,18 @@ import numpy as np
 import pytest
 
 from repro.core import autotune as autotune_mod
-from repro.core import cost_model, probe
+from repro.core import cost_model
 from repro.core.plan import build_plan, build_shard_plan, build_topk_plan
 from repro.core.sort_config import SortConfig
 
 _XLA = SortConfig(tile=256, s=16, direct_max=512, impl="xla")
 _BASE = SortConfig(tile=4096, s=64, direct_max=8192, impl="xla")
 
-# The fixed 12-candidate slice: distinct plan GEOMETRIES (block_rows
+# The fixed 9-candidate slice: distinct plan GEOMETRIES (block_rows
 # variants are identical plans on xla and would only measure timer
-# noise), spanning the strategy, tile, s, fusion and relocation axes.
+# noise), spanning the tile, s and relocation axes.
 SLICE = (
     ("base", {}),
-    ("radix", dict(strategy="radix")),
-    ("merge", dict(strategy="merge")),
     ("tile=2048", dict(tile=2048)),
     ("tile=16384", dict(tile=16384, direct_max=32768)),
     ("tile=1024", dict(tile=1024)),
@@ -45,7 +43,6 @@ SLICE = (
     ("s=128", dict(s=128)),
     ("s=256", dict(s=256)),
     ("scatter", dict(relocation="scatter")),
-    ("nofuse", dict(fuse_sampling=False, fuse_ranking=False)),
     ("t8192s128", dict(tile=8192, s=128)),
 )
 
@@ -103,11 +100,7 @@ def _assert_estimate_properties(log2n: int, kw: dict):
     assert bigger.total > a.total  # monotone at doublings
 
 
-_KW_POOL = (
-    {}, dict(strategy="radix"), dict(strategy="merge"), dict(tile=1024),
-    dict(s=16), dict(relocation="scatter"), dict(fuse_sampling=False,
-                                                 fuse_ranking=False),
-)
+_KW_POOL = ({}, dict(tile=1024), dict(s=16), dict(relocation="scatter"))
 
 try:  # optional dev dep (pip install -e '.[test]')
     from hypothesis import given, settings, strategies as st
@@ -139,21 +132,6 @@ def test_estimate_covers_every_plan_type():
         cost_model.estimate(object())
     d = sp.as_dict()
     assert d["total"] == sp.total and "hbm_bytes" in d
-
-
-def test_priors_feed_strategy_dependent_terms():
-    n = 1 << 18
-    merge_plan = build_plan(
-        n, "int32", dataclasses.replace(_BASE, strategy="merge")
-    )
-    uni = cost_model.estimate(merge_plan).total
-    srt = cost_model.estimate(
-        merge_plan, priors=cost_model.Priors(sortedness=1.0)
-    ).total
-    assert srt < uni  # sorted prior discounts merge compare work
-    pri = probe.priors_for(np.arange(4096, dtype=np.int32))
-    assert pri.sortedness == 1.0
-    assert isinstance(pri, cost_model.Priors)
 
 
 # ----------------------------------------------------------------------

@@ -94,7 +94,7 @@ def test_paper_map_covers_all_nine_steps():
 @pytest.mark.parametrize(
     "module_name",
     ["repro.core.key_codec", "repro.core.bucket_sort",
-     "repro.core.partial_sort", "repro.core.probe"],
+     "repro.core.partial_sort"],
 )
 def test_module_doctests(module_name):
     import importlib

@@ -18,6 +18,7 @@ import pytest
 
 from repro.core import autotune as autotune_mod
 from repro.core import bucket_sort, partial_sort
+from repro.core.autotune import cache_key
 from repro.core.plan import (
     build_plan,
     build_topk_plan,
@@ -28,6 +29,7 @@ from repro.core.plan import (
     plan_to_dict,
 )
 from repro.core.sort_config import SortConfig
+from repro.kernels import bitonic
 
 _XLA = SortConfig(tile=256, s=16, direct_max=512, impl="xla")
 _PAL = SortConfig(tile=128, s=8, direct_max=256, impl="pallas", interpret=True)
@@ -119,6 +121,18 @@ def test_build_plan_property_deterministic_and_bounded():
             node = node.bucket_plan
 
     prop()
+
+
+def test_extends_config_fingerprint():
+    """Every field that shapes a plan is in the fingerprint; ``plan=``
+    stays out (it selects a plan, it does not shape one)."""
+    a = config_fingerprint(_XLA)
+    for kw in (dict(tile=512), dict(s=32), dict(direct_max=1024),
+               dict(impl="pallas"), dict(interpret=True),
+               dict(block_rows=8), dict(relocation="scatter"),
+               dict(descending=True), dict(row_pad=4)):
+        assert config_fingerprint(dataclasses.replace(_XLA, **kw)) != a, kw
+    assert config_fingerprint(dataclasses.replace(_XLA, plan="autotune")) == a
 
 
 def test_plan_fingerprint_ignores_plan_field():
@@ -257,6 +271,13 @@ def test_default_direct_threshold(n, kind):
         assert root.lp == 16384
 
 
+def test_widest_direct_sort_block_rows():
+    """The widest direct sort (``direct_max`` = 16384 lanes, the 4-chip
+    cell's level-1 buckets) runs 32 rows a grid program when 64 rows
+    come in."""
+    assert bitonic.effective_block_rows(64, 16384, None) == 32
+
+
 @pytest.mark.parametrize("keys", ["uniform", "all_equal"])
 def test_default_direct_sort_past_8192_is_stable(keys, rng):
     """A row of 12000 keys, past the old 8192 threshold, now takes the
@@ -365,3 +386,32 @@ def test_autotune_candidate_space_valid_and_deterministic():
     assert cands[0].cfg.tile == _XLA.tile and cands[0].cfg.s == _XLA.s
     for c in cands:
         assert c.cfg.s <= c.cfg.tile and c.cfg.tile % c.cfg.s == 0
+
+
+def test_candidate_space_keeps_base_first():
+    cands = autotune_mod.candidate_space(_XLA, 100_000, max_trials=16)
+    assert cands[0].cfg == _XLA and cands[0].label == "base"
+
+
+def test_stale_cache_record_retunes_cleanly(tmp_path):
+    """A ``sort_plan/v3`` record (the schema before the local sort
+    became one path) in the plan store must be treated as a miss:
+    plan_for re-tunes and overwrites it with a current record, no
+    crash."""
+    cfg = dataclasses.replace(_XLA, plan="autotune")
+    base = build_plan(2333, "int32", cfg)
+    stale = plan_to_dict(base)
+    stale["schema"] = "sort_plan/v3"
+    path = tmp_path / "plans.json"
+    path.write_text(json.dumps({
+        "schema": "sort_plan_cache/v1",
+        "plans": {cache_key(base): {"plan": stale, "best_us": 1.0}},
+    }))
+    autotune_mod.clear_memo()
+    plan = autotune_mod.plan_for(
+        2333, "int32", cfg, path=str(path), max_trials=3, repeats=1
+    )
+    assert plan.length == 2333
+    fresh = json.loads(path.read_text())["plans"][cache_key(base)]
+    assert fresh["plan"]["schema"] == "sort_plan/v4"
+    autotune_mod.clear_memo()

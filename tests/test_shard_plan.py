@@ -137,13 +137,13 @@ def test_build_shard_plan_deterministic_and_memoized():
     b = build_shard_plan(("data",), 4, 2048, "int32", _XLA)
     assert a is b, "axis-normalized rebuild must hit the assembly memo"
     assert a == b and hash(a) == hash(b)
-    # per-phase sub-plans carry the strategy dispatch of the config
-    radix = build_shard_plan(
+    # per-phase sub-plans carry the pipeline choices of the config
+    scatter = build_shard_plan(
         "data", 4, 2048, "int32",
-        dataclasses.replace(_XLA, strategy="radix"),
+        dataclasses.replace(_XLA, relocation="scatter"),
     )
-    assert radix.run_plan.root.strategy == "radix"
-    assert radix != a and radix.signature() != a.signature()
+    assert scatter.run_plan.root.relocation == "scatter"
+    assert scatter != a and scatter.signature() != a.signature()
 
 
 def test_default_mesh_bucket_phase_goes_direct_at_level_one():
@@ -194,7 +194,6 @@ def test_shard_candidate_space_base_first_covers_all_axes():
     assert cands[0].label == "base"
     assert cands[0].oversample == 8 and cands[0].pair_align == 8
     labels = [c.label for c in cands]
-    assert any(l.startswith("strategy=") for l in labels)
     assert any(l.startswith("oversample=") for l in labels)
     assert any(l.startswith("pair_align=") for l in labels)
     assert len(set(labels)) == len(labels), "candidate space has dupes"

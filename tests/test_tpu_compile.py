@@ -24,7 +24,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core import autotune, bucket_sort, distributed_sort
+from repro.core import bucket_sort, distributed_sort
 from repro.core.plan import build_plan
 from repro.core.sort_config import SortConfig
 from repro.kernels import bitonic, splitter, topk
@@ -74,17 +74,6 @@ def test_tile_sort_compiles(one_chip, num_words):
     vals = jax.ShapeDtypeStruct((64, T), I32, sharding=one_chip)
     _compile(lambda w, v: bitonic.sort_tiles_kv(
         tuple(w), v, interpret=False), words, vals)
-
-
-def test_widest_direct_sort_compiles(one_chip):
-    """The default config's widest direct sort (``SortConfig.direct_max``
-    = 16384 lanes), which the 4-chip cell's bucket phase runs on its
-    9344-long level-1 buckets, at its auto row block (32 rows)."""
-    w = jax.ShapeDtypeStruct((64, 16384), U32, sharding=one_chip)
-    v = jax.ShapeDtypeStruct((64, 16384), I32, sharding=one_chip)
-    assert bitonic.effective_block_rows(64, 16384, None) == 32
-    _compile(lambda w, v: bitonic.sort_tiles_kv(
-        (w,), v, interpret=False), w, v)
 
 
 def test_tile_sort_with_samples_compiles(one_chip):
@@ -246,11 +235,21 @@ def mesh_25m(topo, one_chip):
 
 
 def test_mesh_phase_plans_are_native(mesh_25m):
-    plan, _ = mesh_25m
+    """The phases run native kernels, and the bucket phase ends, below
+    its two bucket levels, in the default config's widest direct sort
+    (``SortConfig.direct_max`` = 16384 lanes) on its 9344-long level-1
+    buckets: this compile is the tier-1 guard on that kernel."""
+    plan, compiled = mesh_25m
     phases = [plan.run_plan, plan.dealt_plan, plan.sample_plan,
               plan.bucket_plan]
     assert all(p.impl == "pallas" and p.interpret is False for p in phases)
     assert [p.num_levels for p in phases] == [2, 2, 0, 2]
+    deepest = plan.bucket_plan.root.bucket_plan.bucket_plan
+    assert deepest.kind == "direct" and deepest.lp == 16384
+    widest = [line for line in compiled.as_text().splitlines()
+              if "tpu_custom_call" in line
+              and re.search(r"u32\[\d+,16384\]", line)]
+    assert widest, "no 16384-lane tile sort in the mesh program"
 
 
 def test_mesh_relocation_gathers_read_vmem(mesh_25m):
@@ -291,14 +290,3 @@ def test_mesh_fits_each_chip(mesh_25m):
     per_chip = (m.argument_size_in_bytes + m.output_size_in_bytes
                 + m.temp_size_in_bytes - m.alias_size_in_bytes)
     assert per_chip < 15.75 * 2**30, per_chip
-
-
-@pytest.mark.parametrize("strategy", ["radix", "merge"])
-def test_strategy_without_native_kernel_raises(strategy):
-    """radix and merge gather inside the kernel, which Mosaic cannot
-    lower: a native plan names the strategy at build time."""
-    cfg = SortConfig(impl="pallas", interpret=False, strategy=strategy)
-    with pytest.raises(ValueError, match=strategy):
-        build_plan(1 << 14, jnp.int32, cfg)
-    labels = [c.label for c in autotune.candidate_space(NATIVE, 1 << 20)]
-    assert not any(strategy in label for label in labels), labels
